@@ -26,23 +26,26 @@ def main():
         emit(f"latency/ppm_block/ns{ns}", us, growth)
         prev = us
 
-    # kernel microbenches (interpret mode: correctness-path timing only)
+    # kernel microbenches (interpret mode off-TPU: correctness-path timing)
+    from repro.kernels import dispatch
     from repro.kernels.aaq_quant.ops import aaq_quantize
+    interp = dispatch.interpret_mode()
     from repro.kernels.aaq_quant.ref import aaq_quantize_ref
     x = jax.random.normal(jax.random.PRNGKey(0), (4096, 128))
-    us_k = time_fn(lambda a: aaq_quantize(a, 8, 4, use_kernel=True).inliers, x)
+    us_k = time_fn(lambda a: aaq_quantize(a, 8, 4, use_kernel=True,
+                                          interpret=interp).inliers, x)
     us_r = time_fn(lambda a: aaq_quantize_ref(a, 8, 4)[0], x)
     emit("kernel/aaq_quant_interp", us_k, f"ref_jnp={us_r:.0f}us")
 
     from repro.kernels.flash_attention.flash_attention import flash_mha_pallas
     from repro.kernels.flash_attention.ref import mha_ref
     q = jax.random.normal(jax.random.PRNGKey(1), (1, 256, 4, 32))
-    us_f = time_fn(lambda a: flash_mha_pallas(a, a, a, causal=True), q)
+    us_f = time_fn(lambda a: flash_mha_pallas(a, a, a, causal=True,
+                                                      interpret=interp), q)
     us_m = time_fn(lambda a: mha_ref(a, a, a, causal=True), q)
     emit("kernel/flash_attn_interp", us_f, f"ref_jnp={us_m:.0f}us")
 
     # same call routed through the dispatch layer, both backends
-    from repro.kernels import dispatch
     us_dp = time_fn(
         lambda a: dispatch.attention(a, a, a, causal=True, backend="pallas"), q)
     us_dr = time_fn(

@@ -31,6 +31,8 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
 
     from repro.kernels import dispatch
+    from repro.launch.compile_cache import place_compile_cache
+    place_compile_cache()
     dispatch.set_backend(args.kernels)
 
     print("name,us_per_call,derived")

@@ -6,10 +6,12 @@ sums (which live in 16-bit fixed point and need no scale):
 
     y[t, :] = sigma[t] * (q[t, :] @ W) + sum_j ovals[t, j] * W[oidx[t, j], :]
 
-The outlier term is a rank-k correction (k <= 4): on TPU it is a tiny gather +
-batched matmul on the VPU while the MXU does the dense integer part.  The
-Pallas kernel in ``repro.kernels.aaq_matmul`` fuses all of it; this module is
-the oracle and the always-works fallback.
+The outlier term is a rank-k correction (k <= 4): each token's k outliers are
+placed in an otherwise-zero (..., H) row by iota compare and contracted with
+W in a second dot — a per-token gather of weight rows would move a (T, k, D)
+copy of W through HBM on TPU.  The Pallas kernel in
+``repro.kernels.aaq_matmul`` fuses all of it; this module is the oracle and
+the always-works fallback.
 """
 from __future__ import annotations
 
@@ -17,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.qtensor import QTensor, unpack_int4
+from repro.core.quantize import place_outliers, quantize
 
 
 def qmatmul(qt: QTensor, w: jax.Array, out_dtype=None) -> jax.Array:
@@ -25,21 +28,19 @@ def qmatmul(qt: QTensor, w: jax.Array, out_dtype=None) -> jax.Array:
     out_dtype = out_dtype or qt.orig_dtype
     q = unpack_int4(qt.inliers) if qt.bits == 4 else qt.inliers
     q = q[..., :qt.feature_dim]
-    # Integer contraction with f32 accumulation (MXU int8 path on real TPU).
-    acc = jax.lax.dot_general(
-        q, w.astype(jnp.float32),
-        dimension_numbers=(((q.ndim - 1,), (0,)), ((), ())),
+    wf = w.astype(jnp.float32)
+    dot = lambda a: jax.lax.dot_general(
+        a, wf, dimension_numbers=(((a.ndim - 1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    y = acc * qt.scales                                     # scale once, at the end
+    # Integer contraction with f32 accumulation (MXU int8 path on real TPU).
+    y = dot(q) * qt.scales                                  # scale once, at the end
     if qt.k_outliers:
-        wo = jnp.take(w.astype(jnp.float32), qt.outlier_idx, axis=0)  # (..., k, D)
-        y = y + jnp.einsum("...k,...kd->...d",
-                           qt.outlier_values.astype(jnp.float32), wo)
+        y = y + dot(place_outliers(jnp.zeros(q.shape, jnp.float32),
+                                   qt.outlier_values, qt.outlier_idx))
     return y.astype(out_dtype)
 
 
 def qmatmul_fused_ref(x: jax.Array, w: jax.Array, bits: int, k_outliers: int,
                       out_dtype=None) -> jax.Array:
     """quantize(x) then qmatmul — the end-to-end op models call."""
-    from repro.core.quantize import quantize
     return qmatmul(quantize(x, bits, k_outliers), w, out_dtype or x.dtype)

@@ -68,8 +68,10 @@ def qmax(bits: int) -> int:
 def pack_int4(q: jax.Array) -> jax.Array:
     """Pack int8 values in [-8,7] pairwise into nibble-packed int8 carriers."""
     assert q.shape[-1] % 2 == 0, "int4 packing needs an even feature dim"
-    lo = q[..., 0::2] & 0x0F
-    hi = (q[..., 1::2] & 0x0F) << 4
+    h, ax = q.shape[-1], q.ndim - 1
+    # strided lax slices: a ``q[..., 0::2]`` index lowers to a gather on TPU
+    lo = jax.lax.slice_in_dim(q, 0, h, stride=2, axis=ax) & 0x0F
+    hi = (jax.lax.slice_in_dim(q, 1, h, stride=2, axis=ax) & 0x0F) << 4
     return (lo | hi).astype(jnp.int8)
 
 

@@ -1,1 +1,1 @@
-from repro.kernels.aaq_matmul.ops import aaq_linear, qtensor_matmul
+from repro.kernels.aaq_matmul.ops import aaq_linear

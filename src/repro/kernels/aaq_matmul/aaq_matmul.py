@@ -3,13 +3,16 @@
 y[t, :] = sigma[t] * (q[t, :] @ W)  +  sum_j ovals[t, j] * W[oidx[t, j], :]
 
 Design notes (TPU adaptation of the RMPU, see DESIGN.md §2):
-  * INT4 inliers arrive nibble-packed (half the HBM traffic of INT8); they
-    are unpacked and widened in VMEM — the MXU consumes the widened block.
+  * INT4 inliers arrive nibble-packed (half the HBM traffic of INT8).  The
+    low nibbles are the even feature columns and the high nibbles the odd
+    ones, so the kernel sign-extends each half in VMEM and runs two
+    half-width dots against the even and odd weight rows (split once, in
+    the wrapper) — no lane interleave is ever built.
   * The per-token scale multiplies the *accumulated* row once — LightNobel's
     deferred dequantization. No f32 copy of the activation ever exists in HBM.
-  * Outliers are a rank-k correction (k <= 4): a VMEM gather of k weight rows
-    per token + a small FMA — compute proportional to k, exactly like the
-    ASIC's "16 x 4-bit units per outlier" sizing, not a dense second matmul.
+  * Outliers are a rank-k correction (k <= 4): the k (index, value) pairs of
+    each token are scattered into a sparse (BT, H) row by iota compare and
+    contracted against the same weight block, instead of a row gather.
   * Grid: (T/block_t, D/block_d); the contraction dim H (= 128 in PPM) stays
     whole per block — MXU-aligned and small enough that no H-tiling is needed.
 """
@@ -20,25 +23,45 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _qmm_kernel(inl_ref, scale_ref, ovals_ref, oidx_ref, w_ref, o_ref, *,
+def _outlier_rows(ovals, oidx, width: int, stride: int, offset: int):
+    """(BT, width) f32 rows holding ovals[t, j] at column c where
+    oidx[t, j] == stride * c + offset, zero elsewhere."""
+    col = stride * jax.lax.broadcasted_iota(
+        jnp.int32, (oidx.shape[0], width), 1) + offset
+    rows = jnp.zeros(col.shape, jnp.float32)
+    for j in range(oidx.shape[1]):
+        rows = rows + jnp.where(col == oidx[:, j:j + 1], ovals[:, j:j + 1],
+                                0.0)
+    return rows
+
+
+def _qmm_kernel(inl_ref, scale_ref, ovals_ref, oidx_ref, *refs,
                 bits: int, k: int, out_dtype):
-    q = inl_ref[...]                                         # (BT, H or H/2)
+    *w_refs, o_ref = refs                                    # 1 or 2 weights
+    dot = functools.partial(jax.lax.dot, preferred_element_type=jnp.float32)
+    q = inl_ref[...].astype(jnp.int32)                       # (BT, H or H/2)
+    ws = [w[...].astype(jnp.float32) for w in w_refs]
     if bits == 4:
-        lo = (q << 4) >> 4
-        hi = q >> 4
-        q = jnp.stack([lo, hi], axis=-1).reshape(q.shape[0], -1)
-    w = w_ref[...].astype(jnp.float32)                       # (H, BD)
-    acc = jax.lax.dot(q.astype(jnp.float32), w,
-                      preferred_element_type=jnp.float32)    # (BT, BD)
+        lo = (q << 28) >> 28                                 # even columns
+        hi = q >> 4                                          # odd columns
+        w_even, w_odd = ws
+        acc = (dot(lo.astype(jnp.float32), w_even)
+               + dot(hi.astype(jnp.float32), w_odd))
+    else:
+        acc = dot(q.astype(jnp.float32), ws[0])              # (BT, BD)
     y = acc * scale_ref[...]                                 # deferred scale
     if k > 0:
         oidx = oidx_ref[...]                                 # (BT, K)
-        ovals = ovals_ref[...].astype(jnp.float32)           # (BT, K)
-        wo = jnp.take(w, oidx.reshape(-1), axis=0)           # (BT*K, BD)
-        wo = wo.reshape(*oidx.shape, -1)                     # (BT, K, BD)
-        y = y + jnp.einsum("tk,tkd->td", ovals, wo)
+        ovals = ovals_ref[...].astype(jnp.float32)
+        if bits == 4:
+            half = q.shape[1]
+            y = y + dot(_outlier_rows(ovals, oidx, half, 2, 0), w_even)
+            y = y + dot(_outlier_rows(ovals, oidx, half, 2, 1), w_odd)
+        else:
+            y = y + dot(_outlier_rows(ovals, oidx, q.shape[1], 1, 0), ws[0])
     o_ref[...] = y.astype(out_dtype)
 
 
@@ -46,7 +69,7 @@ def _qmm_kernel(inl_ref, scale_ref, ovals_ref, oidx_ref, w_ref, o_ref, *,
                                              "out_dtype", "interpret"))
 def aaq_matmul_pallas(inliers, scales, ovals, oidx, w, *, bits: int,
                       block_t: int = 256, block_d: int = 256,
-                      out_dtype=jnp.float32, interpret: bool = True):
+                      out_dtype=jnp.float32, interpret: bool):
     t = inliers.shape[0]
     h, d = w.shape
     k = ovals.shape[-1]
@@ -65,6 +88,10 @@ def aaq_matmul_pallas(inliers, scales, ovals, oidx, w, *, bits: int,
     if k == 0:  # keep kernel arity fixed; dummy zero-width-safe operands
         ovals = jnp.zeros((tp, 1), jnp.bfloat16)
         oidx = jnp.zeros((tp, 1), jnp.int32)
+    # even / odd weight rows; lax slices, since a ``w[0::2]`` index lowers to
+    # a gather on TPU
+    ws = ((jax.lax.slice_in_dim(w, 0, h, stride=2),
+           jax.lax.slice_in_dim(w, 1, h, stride=2)) if bits == 4 else (w,))
     kernel = functools.partial(_qmm_kernel, bits=bits, k=k,
                                out_dtype=jnp.dtype(out_dtype))
     y = pl.pallas_call(
@@ -75,10 +102,12 @@ def aaq_matmul_pallas(inliers, scales, ovals, oidx, w, *, bits: int,
             pl.BlockSpec((bt, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((bt, kk), lambda i, j: (i, 0)),
             pl.BlockSpec((bt, kk), lambda i, j: (i, 0)),
-            pl.BlockSpec((h, bd), lambda i, j: (0, j)),
-        ],
+        ] + [pl.BlockSpec((wp.shape[0], bd), lambda i, j: (0, j))
+             for wp in ws],
         out_specs=pl.BlockSpec((bt, bd), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((tp, dp), jnp.dtype(out_dtype)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(inliers, scales, ovals, oidx, w)
+    )(inliers, scales, ovals, oidx, *ws)
     return y[:t, :d]

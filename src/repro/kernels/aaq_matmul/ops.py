@@ -5,9 +5,10 @@ place of ``scheme.linear`` (see models/ppm and EXPERIMENTS.md §Perf).
 """
 from __future__ import annotations
 
+import math
+
 import jax
 
-from repro.core.qtensor import QTensor
 from repro.kernels.aaq_matmul.aaq_matmul import aaq_matmul_pallas
 from repro.kernels.aaq_matmul.ref import aaq_matmul_ref
 from repro.kernels.aaq_quant.ops import aaq_quantize
@@ -15,12 +16,11 @@ from repro.kernels.aaq_quant.ops import aaq_quantize
 
 def aaq_linear(x: jax.Array, w: jax.Array, *, bits: int, k_outliers: int,
                block_t: int = 256, block_d: int = 256,
-               use_kernel: bool = True, interpret: bool = True) -> jax.Array:
+               use_kernel: bool = True, interpret: bool) -> jax.Array:
     """x (..., H) @ w (H, D) through the packed AAQ path."""
     lead = x.shape[:-1]
     qt = aaq_quantize(x, bits, k_outliers, block_t=block_t,
                       use_kernel=use_kernel, interpret=interpret)
-    import math
     nt = math.prod(lead) if lead else 1
     flat = lambda a: a.reshape(nt, a.shape[-1])
     if use_kernel:
@@ -32,18 +32,4 @@ def aaq_linear(x: jax.Array, w: jax.Array, *, bits: int, k_outliers: int,
         y = aaq_matmul_ref(flat(qt.inliers), flat(qt.scales),
                            flat(qt.outlier_values), flat(qt.outlier_idx),
                            w, bits=bits, out_dtype=x.dtype)
-    return y.reshape(*lead, w.shape[-1])
-
-
-def qtensor_matmul(qt: QTensor, w: jax.Array, *, block_t: int = 256,
-                   block_d: int = 256, interpret: bool = True) -> jax.Array:
-    """Kernel-backed matmul for an already-packed QTensor."""
-    lead = qt.token_shape
-    import math
-    nt = math.prod(lead) if lead else 1
-    flat = lambda a: a.reshape(nt, a.shape[-1])
-    y = aaq_matmul_pallas(flat(qt.inliers), flat(qt.scales),
-                          flat(qt.outlier_values), flat(qt.outlier_idx),
-                          w, bits=qt.bits, block_t=block_t, block_d=block_d,
-                          out_dtype=qt.orig_dtype, interpret=interpret)
     return y.reshape(*lead, w.shape[-1])

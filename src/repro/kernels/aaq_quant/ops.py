@@ -10,7 +10,7 @@ from repro.kernels.aaq_quant.ref import aaq_quantize_ref
 
 def aaq_quantize(x: jax.Array, bits: int, k_outliers: int, *,
                  block_t: int = 256, use_kernel: bool = True,
-                 interpret: bool = True) -> QTensor:
+                 interpret: bool) -> QTensor:
     """Quantize an activation of any rank; token axis = -1."""
     shape = x.shape
     flat = x.reshape(-1, shape[-1])

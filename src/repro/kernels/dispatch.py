@@ -18,6 +18,12 @@ An explicit ``pallas`` request off-TPU runs the kernels in interpret mode
 (``pl.pallas_call(interpret=True)``), so CPU CI executes the real kernel
 bodies — same grid, same block program — without TPU hardware.
 
+Under a mesh (the sharded serving tier traces inside ``act_rules`` that
+carry ``KERNEL_MESH``) a Pallas call is wrapped in ``jax.shard_map``:
+GSPMD cannot partition a Mosaic kernel, so each device runs the kernel on
+its shard — attention split over heads, the AAQ linear over tokens — or,
+where the shape does not divide the mesh, on a replicated copy.
+
 Counters: each routed call bumps ``counters[...]`` at *trace* time.  Inside
 ``jit``/``scan`` that is once per compilation (or once per scanned body
 trace), not once per executed step; the parity suite uses the counters to
@@ -30,11 +36,13 @@ import math
 import os
 
 import jax
+from jax.sharding import PartitionSpec as P
 
 from repro.core.qmatmul import qmatmul_fused_ref
 from repro.kernels.aaq_matmul.ops import aaq_linear
 from repro.kernels.flash_attention.flash_attention import flash_mha_pallas
 from repro.kernels.flash_attention.ref import mha_chunked
+from repro.parallel.sharding import KERNEL_MESH, MODEL, rule_value
 
 REF = "ref"
 PALLAS = "pallas"
@@ -234,6 +242,25 @@ def describe(backend: str | None = None, *, seq: int | None = None,
 # --------------------------------------------------------------------------
 # routed ops
 # --------------------------------------------------------------------------
+def _per_device(fn, args, specs, out_spec):
+    """``fn(*args)``, or under a kernel mesh, ``fn`` on each device's shard
+    (``specs`` name the ``MODEL``-split axis of each arg)."""
+    mesh = rule_value(KERNEL_MESH)
+    if mesh is None:
+        return fn(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=out_spec, check_vma=False)(*args)
+
+
+def _split(*sizes):
+    """``MODEL`` when every size divides the kernel mesh's model axis, else
+    None (each device runs the whole call)."""
+    mesh = rule_value(KERNEL_MESH)
+    if mesh is None:
+        return None
+    return MODEL if all(s % mesh.shape[MODEL] == 0 for s in sizes) else None
+
+
 def attention(q, k, v, *, bias=None, causal=False, window=None,
               kv_valid_len=None, softmax_scale=None, q_chunk=512,
               block_q=128, block_k=128, backend=None):
@@ -250,10 +277,24 @@ def attention(q, k, v, *, bias=None, causal=False, window=None,
         if interp:
             block_q = max(block_q, min(q.shape[1], INTERP_BLOCK_SEQ))
             block_k = max(block_k, min(k.shape[1], INTERP_BLOCK_SEQ))
-        return flash_mha_pallas(q, k, v, bias, kv_valid_len, causal=causal,
-                                window=window, softmax_scale=softmax_scale,
-                                block_q=block_q, block_k=block_k,
-                                interpret=interp)
+        has_bias, has_kvl = bias is not None, kv_valid_len is not None
+
+        def call(q, k, v, *rest):
+            return flash_mha_pallas(
+                q, k, v, rest[0] if has_bias else None,
+                rest[-1] if has_kvl else None, causal=causal, window=window,
+                softmax_scale=softmax_scale, block_q=block_q,
+                block_k=block_k, interpret=interp)
+
+        h = _split(q.shape[2], k.shape[2])              # heads are independent
+        args, specs = [q, k, v], [P(None, None, h, None)] * 3
+        if has_bias:
+            args.append(bias)
+            specs.append(P(None, h, None, None))
+        if has_kvl:
+            args.append(kv_valid_len)
+            specs.append(P())
+        return _per_device(call, args, specs, P(None, None, h, None))
     counters["attention.ref"] += 1
     return mha_chunked(q, k, v, bias=bias, causal=causal, window=window,
                        kv_valid_len=kv_valid_len, softmax_scale=softmax_scale,
@@ -276,9 +317,14 @@ def quantized_linear(x, w, *, bits: int, k_outliers: int, bias=None,
         interp = interpret_mode()
         block_t = min(max(n_tokens, 1), INTERP_BLOCK_T) if interp else 256
         block_d = min(w.shape[-1], INTERP_BLOCK_D) if interp else 256
-        y = aaq_linear(x, w, bits=bits, k_outliers=k_outliers,
-                       use_kernel=True, interpret=interp,
-                       block_t=block_t, block_d=block_d)
+        x2 = x.reshape(n_tokens, x.shape[-1])
+        t = _split(n_tokens)                            # tokens are independent
+        y = _per_device(
+            lambda a, b: aaq_linear(a, b, bits=bits, k_outliers=k_outliers,
+                                    use_kernel=True, interpret=interp,
+                                    block_t=block_t, block_d=block_d),
+            (x2, w), (P(t, None), P()), P(t, None))
+        y = y.reshape(*x.shape[:-1], w.shape[-1])
     else:
         counters["qmatmul.ref"] += 1
         y = qmatmul_fused_ref(x, w, bits, k_outliers)

@@ -10,9 +10,11 @@ leaves VMEM.  Supports:
   * causal and sliding-window masks (LM archs),
   * kv_valid_len masking (decode steps with a partially-filled KV cache).
 
-Grid = (B, Hq, nQ, nKV), KV innermost; the running (m, l, o) state lives in
-the revisited output blocks, finalized on the last KV step.  Block shapes
-default to (128, 128) — MXU-aligned on the (8,128)/(128,128) tiling.
+The kernel works on a head-major (B, H, S, D) view, so every block's last
+two dims are (rows, D) — the TPU's (8, 128) tiling rule applies to those.
+Grid = (B, Hq, nQ, nKV), KV innermost; the running max / denominator /
+accumulator live in VMEM scratch and the output block is written once, on
+the last KV step.  Block shapes default to (128, 128).
 """
 from __future__ import annotations
 
@@ -21,6 +23,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
 
@@ -28,17 +31,10 @@ NEG = -1e30
 def _flash_kernel(*refs, nkv: int, block_q: int, block_k: int,
                   causal: bool, window, scale: float, has_bias: bool,
                   has_kvlen: bool):
-    if has_bias and has_kvlen:
-        q_ref, k_ref, v_ref, bias_ref, kvlen_ref, o_ref, m_ref, l_ref = refs
-    elif has_bias:
-        q_ref, k_ref, v_ref, bias_ref, o_ref, m_ref, l_ref = refs
-        kvlen_ref = None
-    elif has_kvlen:
-        q_ref, k_ref, v_ref, kvlen_ref, o_ref, m_ref, l_ref = refs
-        bias_ref = None
-    else:
-        q_ref, k_ref, v_ref, o_ref, m_ref, l_ref = refs
-        bias_ref = kvlen_ref = None
+    q_ref, k_ref, v_ref, *rest = refs
+    bias_ref = rest.pop(0) if has_bias else None
+    kvlen_ref = rest.pop(0) if has_kvlen else None
+    o_ref, m_ref, l_ref, acc_ref = rest
 
     i, j = pl.program_id(2), pl.program_id(3)
 
@@ -46,11 +42,11 @@ def _flash_kernel(*refs, nkv: int, block_q: int, block_k: int,
     def _init():
         m_ref[...] = jnp.full(m_ref.shape, NEG, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)                # (BQ, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)                # (BK, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)                # (BK, D)
+    q = q_ref[0, 0].astype(jnp.float32)                      # (BQ, D)
+    k = k_ref[0, 0].astype(jnp.float32)                      # (BK, D)
+    v = v_ref[0, 0].astype(jnp.float32)                      # (BK, D)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     if bias_ref is not None:
@@ -64,27 +60,24 @@ def _flash_kernel(*refs, nkv: int, block_q: int, block_k: int,
     if window is not None:
         ok &= kpos > qpos - window
     if kvlen_ref is not None:
-        ok &= kpos < kvlen_ref[0, 0]
+        ok &= kpos < kvlen_ref[0]                            # (1, 1) bcast
     s = jnp.where(ok, s, NEG)
 
-    m_prev = m_ref[0, :, 0]                                  # (BQ,)
-    l_prev = l_ref[0, :, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_ref[...]                                      # (BQ, 1)
+    l_prev = l_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     p = jnp.where(ok, p, 0.0)                                # kill fully-masked
     alpha = jnp.exp(m_prev - m_new)
-    l_new = alpha * l_prev + jnp.sum(p, axis=-1)
-    o_prev = o_ref[0, :, 0, :]
-    o_new = o_prev * alpha[:, None] + jax.lax.dot(
+    l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+    m_ref[...] = m_new
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
         p, v, preferred_element_type=jnp.float32)
-    m_ref[0, :, 0] = m_new
-    l_ref[0, :, 0] = l_new
-    o_ref[0, :, 0, :] = o_new
 
     @pl.when(j == nkv - 1)
     def _final():
-        l = l_ref[0, :, 0]
-        o_ref[0, :, 0, :] = o_ref[0, :, 0, :] / jnp.maximum(l, 1e-30)[:, None]
+        o_ref[0, 0] = (acc_ref[...]
+                       / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -92,7 +85,7 @@ def _flash_kernel(*refs, nkv: int, block_q: int, block_k: int,
                               "block_q", "block_k", "interpret"))
 def flash_mha_pallas(q, k, v, bias=None, kv_valid_len=None, *,
                      causal=False, window=None, softmax_scale=None,
-                     block_q=128, block_k=128, interpret=True):
+                     block_q=128, block_k=128, interpret: bool):
     """q (B,Sq,Hq,D); k,v (B,Skv,Hkv,D); bias (Bb,Hq,Sq,Skv); -> (B,Sq,Hq,D)."""
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
@@ -118,45 +111,46 @@ def flash_mha_pallas(q, k, v, bias=None, kv_valid_len=None, *,
     sqp, skvp = q.shape[1], k.shape[1]
     nq, nkv = sqp // bq, skvp // bk
 
+    # head-major views: blocks are (1, 1, rows, D)
+    qh, kh, vh = (jnp.swapaxes(a, 1, 2) for a in (q, k, v))
     has_bias = bias is not None
     has_kvlen = kv_valid_len is not None
     in_specs = [
-        pl.BlockSpec((1, bq, 1, d), lambda b_, h, i_, j_: (b_, i_, h, 0)),
-        pl.BlockSpec((1, bk, 1, d),
-                     lambda b_, h, i_, j_: (b_, j_, h // group, 0)),
-        pl.BlockSpec((1, bk, 1, d),
-                     lambda b_, h, i_, j_: (b_, j_, h // group, 0)),
+        pl.BlockSpec((1, 1, bq, d), lambda b_, h, i_, j_: (b_, h, i_, 0)),
+        pl.BlockSpec((1, 1, bk, d),
+                     lambda b_, h, i_, j_: (b_, h // group, j_, 0)),
+        pl.BlockSpec((1, 1, bk, d),
+                     lambda b_, h, i_, j_: (b_, h // group, j_, 0)),
     ]
-    args = [q, k, v]
+    args = [qh, kh, vh]
     if has_bias:
         bgroup = b // bias.shape[0]
         in_specs.append(pl.BlockSpec(
             (1, 1, bq, bk), lambda b_, h, i_, j_: (b_ // bgroup, h, i_, j_)))
         args.append(bias)
     if has_kvlen:
-        kvl = kv_valid_len.reshape(b, 1).astype(jnp.int32)
-        in_specs.append(pl.BlockSpec((1, 1), lambda b_, h, i_, j_: (b_, 0)))
+        # (B, 1, 1): the block's last two dims equal the array's
+        kvl = kv_valid_len.reshape(b, 1, 1).astype(jnp.int32)
+        in_specs.append(pl.BlockSpec((1, 1, 1),
+                                     lambda b_, h, i_, j_: (b_, 0, 0)))
         args.append(kvl)
 
-    out_shape = [
-        jax.ShapeDtypeStruct((b, sqp, hq, d), jnp.float32),
-        jax.ShapeDtypeStruct((b, sqp, hq), jnp.float32),
-        jax.ShapeDtypeStruct((b, sqp, hq), jnp.float32),
-    ]
-    out_specs = [
-        pl.BlockSpec((1, bq, 1, d), lambda b_, h, i_, j_: (b_, i_, h, 0)),
-        pl.BlockSpec((1, bq, 1), lambda b_, h, i_, j_: (b_, i_, h)),
-        pl.BlockSpec((1, bq, 1), lambda b_, h, i_, j_: (b_, i_, h)),
-    ]
     kernel = functools.partial(
         _flash_kernel, nkv=nkv, block_q=bq, block_k=bk, causal=causal,
         window=window, scale=scale, has_bias=has_bias, has_kvlen=has_kvlen)
-    o, _, _ = pl.pallas_call(
+    o = pl.pallas_call(
         kernel,
         grid=(b, hq, nq, nkv),
         in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
+        out_specs=pl.BlockSpec((1, 1, bq, d),
+                               lambda b_, h, i_, j_: (b_, h, i_, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, hq, sqp, d), q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, 1), jnp.float32),
+                        pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
     )(*args)
-    return o[:, :sq].astype(q.dtype)
+    return jnp.swapaxes(o, 1, 2)[:, :sq]

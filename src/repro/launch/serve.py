@@ -38,11 +38,21 @@ interpret mode.  ``--report`` rows record the backend each batch ran under.
         --buckets 32,64 --mesh 2x4 --shard-threshold 64
     PYTHONPATH=src python -m repro.launch.serve --mode ppm \
         --buckets 1024 --chunk-size auto --mem-budget-mb 512 --no-fidelity
+    PYTHONPATH=src python -m repro.launch.serve --mode ppm \
+        --ppm-config esmfold_ppm --buckets 256,512,1024 --chunk-size auto \
+        --mem-budget-mb 6000 --no-fidelity --min-len 100 --max-len 1000
     PYTHONPATH=src python -m repro.launch.serve --mode lm --arch qwen1.5-0.5b
     PYTHONPATH=src python -m repro.launch.serve --mode lm --n 8 \
         --quant-kv --mem-budget-mb 4 --drift-tol 0.1
     PYTHONPATH=src python -m repro.launch.serve --mode lm \
         --listen 127.0.0.1:0 --replicas 2 --quant-kv
+
+``--ppm-config`` names the fold model: ``reduced`` (the default, a
+2-block toy the CPU tests use) or ``esmfold_ppm`` (the published widths:
+48 blocks, Hm 1024, Hz 128, bf16).  Parameters are random, from a fixed
+seed.  The process exits 1 when any request ended FAILED or the
+background driver recorded an error.  Compiled executables persist under
+``JAX_COMPILATION_CACHE_DIR`` (or ``.jax_cache`` in the checkout).
 
 ``--listen HOST:PORT`` switches ppm mode into a network server: an HTTP
 front-end (``POST /v1/fold``, status/SSE/cancel, ``/metrics``) over a
@@ -62,10 +72,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_config, reduce_config, reduce_ppm_config
+from repro.configs import (get_config, get_ppm_config, reduce_config,
+                           reduce_ppm_config)
 from repro.core import make_scheme
 from repro.kernels import dispatch
 from repro.data.pipeline import ProteinSampler
+from repro.launch.compile_cache import place_compile_cache
 from repro.models import lm
 from repro.models.ppm import init_ppm, ppm_forward, tm_score
 from repro.serving import (CSV_HEADER, LM_CSV_HEADER, FleetRouter,
@@ -75,6 +87,33 @@ from repro.serving import (CSV_HEADER, LM_CSV_HEADER, FleetRouter,
                            make_serving_mesh, pad_to_bucket, parse_buckets,
                            parse_chunk_spec)
 from repro.serving.observability.httpd import parse_hostport
+
+
+PPM_CONFIGS = {"reduced": reduce_ppm_config, "esmfold_ppm": get_ppm_config}
+
+
+def ppm_model(name: str, seed: int = 0):
+    """(cfg, params) of the named fold model, params random from ``seed``."""
+    cfg = PPM_CONFIGS[name]()
+    params = jax.jit(init_ppm, static_argnums=1)(jax.random.PRNGKey(seed),
+                                                 cfg)
+    return cfg, params
+
+
+def fold_client(args, cfg, params, buckets, *, mesh=None,
+                cost_model=None) -> FoldClient:
+    """The FoldClient ``--mode ppm`` serves through, built from the CLI
+    args (shared by inline serving, every HTTP replica and chip_smoke)."""
+    return FoldClient(
+        params, cfg, args.scheme, buckets=buckets,
+        max_tokens_per_batch=args.max_tokens_per_batch,
+        max_batch=args.max_batch, mem_budget_mb=args.mem_budget_mb,
+        fidelity=not args.no_fidelity, kernels=args.kernels,
+        mesh=mesh, shard_threshold=args.shard_threshold,
+        inflight_depth=args.inflight_depth,
+        linger_ms=args.batch_linger_ms,
+        adaptive_linger=not args.no_adaptive_linger,
+        chunk_size=args.chunk_size, cost_model=cost_model)
 
 
 def _sample_trace(args) -> list[np.ndarray]:
@@ -147,16 +186,9 @@ def serve_http(args, cfg, params, buckets) -> int:
         # process-wide
         cost_model = (load_cost_table(args.cost_table)
                       if args.cost_table else None)
-        client = FoldClient(
-            params, cfg, args.scheme, buckets=buckets,
-            max_tokens_per_batch=args.max_tokens_per_batch,
-            max_batch=args.max_batch, mem_budget_mb=args.mem_budget_mb,
-            fidelity=not args.no_fidelity, kernels=args.kernels,
-            mesh=make_serving_mesh(args.mesh), shard_threshold=args.shard_threshold,
-            inflight_depth=args.inflight_depth,
-            linger_ms=args.batch_linger_ms,
-            adaptive_linger=not args.no_adaptive_linger,
-            chunk_size=args.chunk_size, cost_model=cost_model)
+        client = fold_client(args, cfg, params, buckets,
+                             mesh=make_serving_mesh(args.mesh),
+                             cost_model=cost_model)
         client.tracer.set_metadata(
             replica=i, scheme=args.scheme,
             kernels=dispatch.describe(args.kernels), buckets=list(buckets),
@@ -204,8 +236,7 @@ def serve_http(args, cfg, params, buckets) -> int:
 
 
 def serve_ppm(args):
-    cfg = reduce_ppm_config()
-    params = init_ppm(jax.random.PRNGKey(0), cfg)
+    cfg, params = ppm_model(args.ppm_config)
     seqs = _sample_trace(args)
     try:
         buckets = parse_buckets(args.buckets, args.min_len, args.max_len)
@@ -246,16 +277,8 @@ def serve_ppm(args):
         except (FileNotFoundError, ValueError) as e:
             print(f"error: {e}")
             return 2
-    client = FoldClient(
-        params, cfg, args.scheme, buckets=buckets,
-        max_tokens_per_batch=args.max_tokens_per_batch,
-        max_batch=args.max_batch, mem_budget_mb=args.mem_budget_mb,
-        fidelity=not args.no_fidelity, kernels=args.kernels,
-        mesh=mesh, shard_threshold=args.shard_threshold,
-        inflight_depth=args.inflight_depth,
-        linger_ms=args.batch_linger_ms,
-        adaptive_linger=not args.no_adaptive_linger,
-        chunk_size=args.chunk_size, cost_model=cost_model)
+    client = fold_client(args, cfg, params, buckets, mesh=mesh,
+                         cost_model=cost_model)
     client.tracer.set_metadata(
         scheme=args.scheme, kernels=dispatch.describe(args.kernels),
         buckets=list(buckets), inflight_depth=args.inflight_depth,
@@ -317,7 +340,8 @@ def serve_ppm(args):
           f"kernels={dispatch.describe(args.kernels)} "
           f"placements={'/'.join(placements) or 'none'} "
           f"chunks={'/'.join(str(c) for c in chunks) or 'none'} "
-          f"max_est_act_mb={s['max_est_act_mb']:.1f}"
+          f"max_est_act_mb={s['max_est_act_mb']:.1f} "
+          f"failed={s['failed']} driver_errors={len(client.driver_errors)}"
           + (f" budget_mb={args.mem_budget_mb:.1f}"
              if args.mem_budget_mb else ""))
     print(f"# queue_wait_ms p50={s['queue_wait_ms']['p50']:.1f} "
@@ -366,7 +390,7 @@ def serve_ppm(args):
                   f"at {server.url}/metrics", flush=True)
             time.sleep(args.metrics_hold_s)
         server.stop()
-    return 0
+    return 1 if s["failed"] or client.driver_errors else 0
 
 
 def _lm_prompts(args, cfg) -> list[np.ndarray]:
@@ -492,7 +516,8 @@ def serve_lm(args):
     adm = client.core.admission
     print(f"# workload=lm arch={args.arch} scheme={scheme} "
           f"served={s['served']}/{s['requests']} rejected={s['rejected']} "
-          f"expired={s['expired']} tokens={s['tokens']} "
+          f"expired={s['expired']} failed={s['failed']} "
+          f"driver_errors={len(client.driver_errors)} tokens={s['tokens']} "
           f"tok/s={s['tokens_per_s']:.1f} compiles={s['compiles']} "
           f"kv_bits_per_value={adm.bits_per_value:.1f} "
           f"kv_bytes_per_req={adm.bytes_per_request} "
@@ -527,12 +552,16 @@ def serve_lm(args):
               f"tol={args.drift_tol:.4e} {'OK' if ok else 'FAIL'}")
         if not ok:
             return 1
-    return 0
+    return 1 if s["failed"] or client.driver_errors else 0
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=["ppm", "lm"], default="ppm")
+    ap.add_argument("--ppm-config", choices=sorted(PPM_CONFIGS),
+                    default="reduced",
+                    help="ppm: fold model widths; 'esmfold_ppm' is the "
+                         "published 48-block model, 'reduced' a 2-block toy")
     ap.add_argument("--scheme", default="lightnobel_aaq")
     ap.add_argument("--kernels", choices=list(dispatch.BACKENDS),
                     default=dispatch.AUTO,
@@ -668,7 +697,12 @@ def main(argv=None):
                     help="lm + --quant-kv: run an fp16-KV twin on the "
                          "same prompts and exit 1 if max first-token "
                          "logit drift exceeds this")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    place_compile_cache()
     dispatch.set_backend(args.kernels)   # both modes, both ppm paths
     return serve_ppm(args) if args.mode == "ppm" else serve_lm(args)
 

@@ -17,20 +17,11 @@ import jax
 import jax.numpy as jnp
 
 
-def _axis_size(axis_name: str) -> int:
-    """``jax.lax.axis_size`` across JAX versions — 0.4.x lacks it; there the
-    classic ``psum(1, axis)`` idiom constant-folds to the static size."""
-    try:
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:
-        return jax.lax.psum(1, axis_name)
-
-
 def ring_ag_matmul(x, w, axis_name: str):
     """x: (m, k/p) local shard; w: (k/p, n) matching local rows of the
     weight; computes all_gather(x) @ w_full without materializing the
     gather.  Must run inside shard_map with ``axis_name``."""
-    p = _axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % p) for i in range(p)]
 
@@ -55,7 +46,7 @@ def ring_ag_matmul_ws(x, w_full, axis_name: str):
     """Weight-stationary variant: w_full (k, n) is already resident
     (parameters); x (m, k/p) is the sharded activation.  Each ring step
     consumes one k-shard of w — no weight gather at all."""
-    p = _axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % p) for i in range(p)]
     k = w_full.shape[0]

@@ -69,9 +69,8 @@ def gpipe_apply(blocks, x, cfg, *, mesh, n_micro: int, block_fn=None,
 
     in_specs = (P(axis), P())          # blocks: layer dim over pods
     out_specs = P()
-    from repro.parallel.sharding import shard_map_compat
-    fn_sm = shard_map_compat(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check=False)
+    fn_sm = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=False)
     return fn_sm(blocks, x)
 
 

@@ -31,22 +31,7 @@ from repro.configs.base import ArchConfig, ShapeSpec
 
 DATA = "data"            # logical data axis (maps to ("pod","data") multi-pod)
 MODEL = "model"
-
-
-def shard_map_compat(fn, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across JAX versions.
-
-    Newer JAX exposes ``jax.shard_map(..., check_vma=)``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)``.  Feature-
-    detect at call time so the parallel layer (and tests) run on both.
-    """
-    try:
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    except (AttributeError, TypeError):
-        from jax.experimental.shard_map import shard_map
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=check)
+KERNEL_MESH = "kernel_mesh"   # act-rules entry: the mesh Pallas calls run on
 
 
 def _axis_size(mesh: Mesh, axis) -> int:
@@ -359,6 +344,8 @@ def ppm_serving_rules(mesh: Mesh) -> dict[str, P]:
     tier exists for run at batch 1-2, and the trunk's ``constrain`` calls
     at block boundaries re-pin the sharding so GSPMD keeps the triangular
     ops between them partitioned.  The sequence track (B, N, Hm) is linear
-    in N and stays replicated (no rule = no constraint).
+    in N and stays replicated (no rule = no constraint).  ``KERNEL_MESH``
+    tells ``repro.kernels.dispatch`` to run Pallas calls per device under
+    ``shard_map``, since GSPMD cannot partition a Mosaic kernel.
     """
-    return {"pair": P(None, None, MODEL, None)}
+    return {"pair": P(None, None, MODEL, None), KERNEL_MESH: mesh}

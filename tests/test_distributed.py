@@ -4,14 +4,8 @@ XLA device-count flag must precede jax import, so these run out-of-process).
 Covers: sharded train step under the rules system, GPipe pipeline
 equivalence, ring collective-matmul, elastic restore onto a resized mesh.
 
-Triage note (seed-era "gpipe/ring numeric" failures): both were JAX-version
-API gaps, not numerics — ``jax.shard_map``/``check_vma`` and
-``jax.lax.axis_size`` only exist post-0.4.x.  Fixed by
-``repro.parallel.sharding.shard_map_compat`` (falls back to
-``jax.experimental.shard_map.shard_map(check_rep=)``) and
-``repro.parallel.overlap._axis_size`` (falls back to the ``psum(1, axis)``
-constant-fold idiom); both tests pass on 0.4.37 and the new-API path is
-preserved for newer JAX.
+The gpipe and ring tests drive ``jax.shard_map(check_vma=)`` and
+``jax.lax.axis_size`` directly (the jax 0.9 APIs the parallel layer uses).
 """
 import os
 import subprocess
@@ -96,7 +90,6 @@ def test_ring_ag_matmul_matches_dense():
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
     from repro.parallel.overlap import ring_ag_matmul_ws
-    from repro.parallel.sharding import shard_map_compat
     from repro.launch.mesh import make_mesh
     mesh = make_mesh((8,), ("model",))
     x = jax.random.normal(jax.random.PRNGKey(0), (16, 64))
@@ -106,8 +99,8 @@ def test_ring_ag_matmul_matches_dense():
     def f(xs, wf):
         return ring_ag_matmul_ws(xs, wf, "model")
 
-    fsm = shard_map_compat(f, mesh=mesh, in_specs=(P(None, "model"), P()),
-                           out_specs=P(), check=False)
+    fsm = jax.shard_map(f, mesh=mesh, in_specs=(P(None, "model"), P()),
+                        out_specs=P(), check_vma=False)
     # each shard holds a k-slice of x; ring accumulates the full product
     y = fsm(x, w)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=2e-4, atol=2e-4)

@@ -21,7 +21,7 @@ from repro.kernels.flash_attention.ref import mha_chunked, mha_ref
 def test_aaq_quant_kernel_vs_ref(t, h, bits, k, dtype):
     x = (jax.random.normal(jax.random.PRNGKey(0), (t, h)) * 2).astype(dtype)
     x = x.at[0, 5].set(50.0)
-    qk = aaq_quantize(x, bits, k, use_kernel=True)
+    qk = aaq_quantize(x, bits, k, use_kernel=True, interpret=True)
     qr = quantize(x, bits, k)
     ik = unpack_int4(qk.inliers) if bits == 4 else qk.inliers
     ir = unpack_int4(qr.inliers) if bits == 4 else qr.inliers
@@ -42,7 +42,8 @@ def test_aaq_matmul_kernel_vs_ref(t, h, d, bits, k):
     x = x.at[3, 7].set(-60.0)
     w = jax.random.normal(jax.random.PRNGKey(1), (h, d))
     qt = quantize(x, bits, k)
-    yk = aaq_linear(x, w, bits=bits, k_outliers=k, block_t=64, block_d=64)
+    yk = aaq_linear(x, w, bits=bits, k_outliers=k, block_t=64, block_d=64,
+                    interpret=True)
     yr = qmatmul(qt, w)
     sc = float(jnp.max(qt.scales))
     np.testing.assert_allclose(np.asarray(yk), np.asarray(yr),
@@ -87,7 +88,8 @@ def test_flash_kernel_vs_ref(case, dtype):
     kvlen = (jnp.array([c["Skv"] // 2, c["Skv"]] * (c["B"] // 2), jnp.int32)
              if c["kvlen"] else None)
     o_k = flash_mha_pallas(q, k, v, bias, kvlen, causal=c["causal"],
-                           window=c["window"], block_q=32, block_k=32)
+                           window=c["window"], block_q=32, block_k=32,
+                           interpret=True)
     o_r = mha_ref(q, k, v, bias=bias, causal=c["causal"], window=c["window"],
                   kv_valid_len=kvlen)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
@@ -113,7 +115,8 @@ def test_bias_block_broadcast_consistent_across_paths():
     o_chk = mha_chunked(q, k, v, bias=bias, q_chunk=8)
     np.testing.assert_allclose(np.asarray(o_exp), np.asarray(o_chk),
                                rtol=2e-5, atol=2e-5)
-    o_pal = flash_mha_pallas(q, k, v, bias, block_q=8, block_k=8)
+    o_pal = flash_mha_pallas(q, k, v, bias, block_q=8, block_k=8,
+                             interpret=True)
     np.testing.assert_allclose(np.asarray(o_exp), np.asarray(o_pal),
                                rtol=2e-5, atol=2e-5)
 

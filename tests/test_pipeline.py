@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from _parity import assert_fold_parity
 
 from repro.configs import reduce_ppm_config
 from repro.core import make_scheme
@@ -163,22 +164,22 @@ def test_launch_size_fits_occupancy_and_reuses_cached_sizes():
         r.occupancy, abs=1e-3)
 
 
-def test_exact_fit_batches_beat_static_padding_bitwise():
-    """An occupancy-fitted launch (2 real rows at size 2) equals the same
-    requests padded into a max-size batch, bitwise — the FLOP savings are
-    free of numerics risk."""
-    seqs = [_seq(12), _seq(14)]
-    small = FoldEngine(PARAMS, CFG, "lightnobel_aaq", buckets=(16,),
-                       max_tokens_per_batch=32, max_batch=2)   # cap 2
-    big = FoldEngine(PARAMS, CFG, "lightnobel_aaq", buckets=(16,),
-                     max_tokens_per_batch=64, max_batch=4)     # cap 4
-    big.core._executable(16, 4, big.core.scheme)   # force the padded shape
-    big_res = big.run(seqs + [_seq(13), _seq(11)])
+def test_exact_fit_batches_match_static_padding():
+    """An occupancy-fitted launch (2 real rows at size 2) matches the same
+    requests padded into a max-size batch under the parity contract — the
+    FLOP savings cost no fidelity."""
+    seqs = [_seq(48), _seq(56)]
+    small = FoldEngine(PARAMS, CFG, "lightnobel_aaq", buckets=(64,),
+                       max_tokens_per_batch=128, max_batch=2)  # cap 2
+    big = FoldEngine(PARAMS, CFG, "lightnobel_aaq", buckets=(64,),
+                     max_tokens_per_batch=256, max_batch=4)    # cap 4
+    big.core._executable(64, 4, big.core.scheme)   # force the padded shape
+    big_res = big.run(seqs + [_seq(52), _seq(44)])
     small_res = small.run(seqs)
     assert all(r.launched_batch == 2 for r in small_res)
     assert all(r.launched_batch == 4 for r in big_res)
     for a, b in zip(small_res, big_res[:2]):
-        np.testing.assert_array_equal(a.coords, b.coords)
+        assert_fold_parity("lightnobel_aaq", a.coords, b.coords)
 
 
 # --------------------------------------------------------------------------

@@ -273,3 +273,49 @@ def test_sharded_serving_parity_admission_and_steady_state():
     for marker in ("FLIP_REJECT_OK", "FLIP_ADMIT_OK", "STEADY_OK",
                    "REPORT_OK", "AAQ_TM_OK", "FP_PARITY_OK"):
         assert marker in out
+
+
+def test_sharded_pallas_kernels_run_per_device():
+    """GSPMD cannot partition a Mosaic kernel, so under the sharded rules
+    dispatch runs each Pallas call per device (``shard_map``).  On a 1x4
+    CPU mesh with the kernels interpreted, the sharded serve must keep the
+    Pallas path (trace counters, backend label) and hold the placement
+    parity contract against the single-device Pallas serve."""
+    out = _run("""
+    import numpy as np, jax
+    from repro.configs import reduce_ppm_config
+    from repro.kernels import dispatch
+    from repro.models.ppm import init_ppm, tm_score
+    from repro.serving import FoldClient, make_serving_mesh
+
+    cfg = reduce_ppm_config()
+    params = init_ppm(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(7)
+    seqs = [rng.integers(0, 20, n).astype(np.int32) for n in (50, 60)]
+    mesh = make_serving_mesh("1x4")
+    kw = dict(buckets=(64,), max_tokens_per_batch=128, max_batch=2,
+              kernels="pallas")
+    for scheme in ("lightnobel_aaq", None):
+        sh = FoldClient(params, cfg, scheme, mesh=mesh, shard_threshold=64,
+                        **kw)
+        dispatch.reset_counters()
+        a = [h.result() for h in [sh.submit(s) for s in seqs]]
+        assert dispatch.counters["attention.pallas"] > 0
+        assert dispatch.counters["attention.ref"] == 0
+        if scheme:
+            assert dispatch.counters["qmatmul.pallas"] > 0
+            assert dispatch.counters["qmatmul.ref"] == 0
+        si = FoldClient(params, cfg, scheme, **kw)
+        b = [h.result() for h in [si.submit(s) for s in seqs]]
+        for x, y in zip(a, b):
+            assert x.ok and y.ok, (x.reason, y.reason)
+            assert x.placement == "mesh:1x4" and y.placement == "single"
+            assert x.kernel_backend == "pallas-interpret", x.kernel_backend
+            if scheme:
+                assert float(tm_score(x.coords, y.coords)) >= 0.995
+            else:
+                np.testing.assert_allclose(x.coords, y.coords, rtol=1e-4,
+                                           atol=1e-4)
+    print("SHARDED_PALLAS_OK")
+    """)
+    assert "SHARDED_PALLAS_OK" in out

@@ -5,8 +5,9 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core import (dequantize, pack_int4, qmatmul, qmax,
+from repro.core import (dequantize, fake_quant, pack_int4, qmatmul, qmax,
                         quant_rmse, quantize, unpack_int4)
+from repro.core.quantize import top_k_abs
 from repro.core.policy import AAQConfig, GROUP_A, GROUP_B, GROUP_C
 
 settings.register_profile("ci", max_examples=25, deadline=None)
@@ -57,6 +58,28 @@ def test_int4_pack_roundtrip(seed, h):
     q = np.asarray(jax.random.randint(jax.random.PRNGKey(seed), (7, h), -8, 8),
                    np.int8)
     np.testing.assert_array_equal(np.asarray(unpack_int4(pack_int4(jnp.asarray(q)))), q)
+
+
+@pytest.mark.parametrize("shape,dtype", [((300, 128), jnp.float32),
+                                         ((2, 17, 64), jnp.bfloat16)])
+def test_top_k_abs_matches_lax_top_k(shape, dtype):
+    """Same picks as ``lax.top_k(|x|)``, ties to the lower index included
+    (coarse values make ties common; column 3 copies column 5, negated)."""
+    x = jnp.round(jax.random.normal(jax.random.PRNGKey(1), shape) * 3)
+    x = x.at[..., 3].set(-x[..., 5]).astype(dtype)
+    vals, idx = top_k_abs(x, 4)
+    _, want = jax.lax.top_k(jnp.abs(x), 4)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(want))
+    np.testing.assert_array_equal(
+        np.asarray(vals), np.asarray(jnp.take_along_axis(x, want, -1)))
+
+
+@given(token_arrays(), st.sampled_from([(8, 4), (4, 4), (4, 0), (8, 0)]))
+def test_fake_quant_is_the_packed_round_trip(x, bk):
+    bits, k = bk
+    xj = jnp.asarray(x)
+    np.testing.assert_array_equal(np.asarray(fake_quant(xj, bits, k)),
+                                  np.asarray(dequantize(quantize(xj, bits, k))))
 
 
 @given(token_arrays(), st.sampled_from([(8, 4), (4, 4), (4, 0)]))

@@ -1,10 +1,13 @@
 """Substrate tests: data determinism, checkpointing, fault tolerance,
-gradient compression, optimizer, schedules, HLO analyzer."""
+gradient compression, optimizer, schedules, HLO analyzer, compile-cache
+placement."""
 import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint import checkpointing as ckpt
@@ -174,3 +177,28 @@ def test_grad_compress_error_feedback_unbiased():
 def test_grad_compress_wire_bytes():
     g = {"w": jnp.zeros((16, 32))}
     assert grad_compress.wire_bytes(g, bits=8) == 16 * 32 + 16 * 4
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(monkeypatch, tmp_path, from_env):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and nothing is set in code (JAX
+    reads the variable itself); otherwise the cache is the fixed,
+    gitignored ``.jax_cache`` at the checkout root."""
+    from repro.launch import compile_cache as cc
+    prev = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv(cc.ENV, str(tmp_path))
+    else:
+        monkeypatch.delenv(cc.ENV, raising=False)
+    try:
+        got = cc.place_compile_cache()
+        if from_env:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == prev
+        else:
+            root = Path(__file__).resolve().parents[1]
+            assert got == str(root / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+            assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
