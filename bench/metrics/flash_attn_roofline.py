@@ -1,0 +1,7 @@
+"""Kernels: ``flash_mha_pallas``'s roofline share in % (``bench/ops/kernels.py``)."""
+
+from bench.ops.kernels import roofline_share
+
+
+def read(run):
+    return roofline_share(run, "flash_mha_pallas")
