@@ -275,18 +275,26 @@ def block_apply_chunked(p, s, z, cfg, scheme: QuantScheme, chunk: int,
     the unchunked block — only the materialization schedule changes.
     """
     pb = seq_pair_bias_chunked(p["seq_attn"], z, chunk)
-    s = s + tk.seq_attn_apply(p["seq_attn"], s, z, cfg.seq_heads, mask=mask,
-                              pair_bias=pb)
+    # the named scopes of ``trunk.block_apply``, around the same ops
+    with jax.named_scope("seq_attn"):
+        s = s + tk.seq_attn_apply(p["seq_attn"], s, z, cfg.seq_heads,
+                                  mask=mask, pair_bias=pb)
     s = s + tk.seq_transition_apply(p["seq_trans"], s)
     z = z + opm_chunked(p["opm"], s, chunk)
-    z = z + tri_mul_chunked(p["tri_mul_out"], z, scheme, True, "tri_mul_out",
-                            chunk, mask=mask)
-    z = z + tri_mul_chunked(p["tri_mul_in"], z, scheme, False, "tri_mul_in",
-                            chunk, mask=mask)
-    z = z + tri_attn_chunked(p["tri_attn_start"], z, scheme, True,
-                             "tri_attn_start", cfg.pair_heads, chunk,
-                             mask=mask)
-    z = z + tri_attn_chunked(p["tri_attn_end"], z, scheme, False,
-                             "tri_attn_end", cfg.pair_heads, chunk, mask=mask)
-    z = z + pair_transition_chunked(p["pair_trans"], z, scheme, chunk)
+    with jax.named_scope("tri_mul_out"):
+        z = z + tri_mul_chunked(p["tri_mul_out"], z, scheme, True,
+                                "tri_mul_out", chunk, mask=mask)
+    with jax.named_scope("tri_mul_in"):
+        z = z + tri_mul_chunked(p["tri_mul_in"], z, scheme, False,
+                                "tri_mul_in", chunk, mask=mask)
+    with jax.named_scope("tri_attn_start"):
+        z = z + tri_attn_chunked(p["tri_attn_start"], z, scheme, True,
+                                 "tri_attn_start", cfg.pair_heads, chunk,
+                                 mask=mask)
+    with jax.named_scope("tri_attn_end"):
+        z = z + tri_attn_chunked(p["tri_attn_end"], z, scheme, False,
+                                 "tri_attn_end", cfg.pair_heads, chunk,
+                                 mask=mask)
+    with jax.named_scope("pair_trans"):
+        z = z + pair_transition_chunked(p["pair_trans"], z, scheme, chunk)
     return s, z

@@ -71,8 +71,9 @@ def ppm_forward(params, aatype: jax.Array, cfg: PPMConfig,
         z_in = z0 + (cm.layernorm(params["recycle_z_ln"], z) if r else 0.0)
         s, z = tk.trunk_apply(params["trunk"], s_in, z_in, cfg, scheme,
                               remat=remat, mask=mask, chunk_size=chunk_size)
-    coords, s_final = st.structure_apply(params["structure"], s, z,
-                                         n_iter=cfg.ipa_iters, mask=mask)
+    with jax.named_scope("structure"):     # its flash attention calls
+        coords, s_final = st.structure_apply(params["structure"], s, z,
+                                             n_iter=cfg.ipa_iters, mask=mask)
     zsym = 0.5 * (z + jnp.swapaxes(z, 1, 2))
     distogram = cm.dense(params["distogram"], zsym)
     return {"coords": coords, "distogram": distogram, "s": s_final, "z": z}

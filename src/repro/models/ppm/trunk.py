@@ -281,18 +281,27 @@ def opm_apply(p, s):
 # one folding block
 # --------------------------------------------------------------------------
 def block_apply(p, s, z, cfg: PPMConfig, scheme: QuantScheme, mask=None):
-    s = s + seq_attn_apply(p["seq_attn"], s, z, cfg.seq_heads, mask=mask)
+    # every op that launches a Pallas kernel (flash attention, the AAQ
+    # quantizer and matmul) runs under a named scope, so the device ops'
+    # metadata names the layer that launched them
+    with jax.named_scope("seq_attn"):
+        s = s + seq_attn_apply(p["seq_attn"], s, z, cfg.seq_heads, mask=mask)
     s = s + seq_transition_apply(p["seq_trans"], s)
     z = z + opm_apply(p["opm"], s)
-    z = z + tri_mul_apply(p["tri_mul_out"], z, scheme, True, "tri_mul_out",
-                          mask=mask)
-    z = z + tri_mul_apply(p["tri_mul_in"], z, scheme, False, "tri_mul_in",
-                          mask=mask)
-    z = z + tri_attn_apply(p["tri_attn_start"], z, scheme, True,
-                           "tri_attn_start", cfg.pair_heads, mask=mask)
-    z = z + tri_attn_apply(p["tri_attn_end"], z, scheme, False,
-                           "tri_attn_end", cfg.pair_heads, mask=mask)
-    z = z + pair_transition_apply(p["pair_trans"], z, scheme)
+    with jax.named_scope("tri_mul_out"):
+        z = z + tri_mul_apply(p["tri_mul_out"], z, scheme, True,
+                              "tri_mul_out", mask=mask)
+    with jax.named_scope("tri_mul_in"):
+        z = z + tri_mul_apply(p["tri_mul_in"], z, scheme, False,
+                              "tri_mul_in", mask=mask)
+    with jax.named_scope("tri_attn_start"):
+        z = z + tri_attn_apply(p["tri_attn_start"], z, scheme, True,
+                               "tri_attn_start", cfg.pair_heads, mask=mask)
+    with jax.named_scope("tri_attn_end"):
+        z = z + tri_attn_apply(p["tri_attn_end"], z, scheme, False,
+                               "tri_attn_end", cfg.pair_heads, mask=mask)
+    with jax.named_scope("pair_trans"):
+        z = z + pair_transition_apply(p["pair_trans"], z, scheme)
     return s, z
 
 

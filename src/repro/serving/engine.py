@@ -81,7 +81,12 @@ device wall time; with ``inflight_depth > 1`` it includes time queued
 behind the previous in-flight batch, and in a cold window it can span a
 NEIGHBOR batch's host-side compile (the device computes on while the host
 compiles, so launch-to-ready is still the honest measure; each batch's
-own compile is always isolated in its own ``compile_ms``).
+own compile is always isolated in its own ``compile_ms``).  The batch's
+own device time is ``device_ms``: from the later of its launch and the
+previous batch's ready time to its own ready time (the ``device`` span on
+its trace track), an upper bound when the host came to ``block`` after the
+device had finished.  With fidelity on, batch k's FP re-run runs after
+batch k is ready and counts in batch k+1's ``device_ms``.
 
 Clock: ``clock`` (default ``time.monotonic``) stamps batch starts on the
 same monotonic clock the client stamps arrivals/deadlines with, so
@@ -104,8 +109,8 @@ from repro.kernels import dispatch
 from repro.serving.costmodel import CostModel
 from repro.serving.longfold import ChunkPolicy
 from repro.serving.metrics import reset_compile_watch
-from repro.serving.observability.profiler import annotate
-from repro.serving.observability.tracing import PROC_ENGINE, Tracer
+from repro.serving.observability.tracing import (PROC_ENGINE, Span, Tracer,
+                                                 gc_pause_s)
 from repro.serving.placement import (PlacementPolicy, lower_sharded,
                                      place_inputs)
 from repro.serving.scheduler import ScheduledBatch, static_batch_for
@@ -144,7 +149,8 @@ class InFlightBatch:
     # tests monkeypatch dispatch with stubs that skip them)
     seq: int = 0                       # monotone batch sequence number
     thread: str = ""                   # trace track, "batch-NNNN"
-    flight_span: Any = None            # open "in_flight" span (ends at retire)
+    launched_at: float = 0.0           # tracer clock at launch
+    device_s: float = 0.0              # queue-free device time (at retire)
 
 
 class EngineCore:
@@ -204,6 +210,9 @@ class EngineCore:
         # request timestamps; the client re-exports it as ``client.tracer``
         self.tracer = tracer if tracer is not None else Tracer(clock=clock)
         self._batch_seq = 0
+        # tracer clock at which the last retired batch's outputs were
+        # ready: the device starts the next batch no earlier
+        self._ready_at = float("-inf")
         # every admission verdict (probes included) feeds the metrics
         # registry; late-bound through self.metrics because run() swaps the
         # metrics object per trace
@@ -405,21 +414,21 @@ class EngineCore:
         seq = self._batch_seq
         self._batch_seq += 1
         thread = f"batch-{seq:04d}"      # one trace track per batch: the
-        # dispatch/in_flight/retire chain of batch k+1 visibly overlaps
+        # dispatch/device/retire chain of batch k+1 visibly overlaps
         # batch k's track in the exported Perfetto timeline
         tr = self.tracer
         d_span = tr.begin("dispatch", process=PROC_ENGINE, thread=thread,
                           batch_seq=seq, bucket=bucket,
                           batch_size=len(batch.requests),
                           scheme=self.scheme.name,
-                          requests=[r.request_id for r in batch.requests])
+                          requests=[r.request_id for r in batch.requests],
+                          gc_s=gc_pause_s())
         placement = self.placement.placement_for(bucket)
         try:
-            with annotate(f"serve.dispatch/{bucket}"):
+            with tr.annotate(f"serve.dispatch/{bucket}", seq):
                 launched_b = self.launch_size_for(
                     bucket, len(batch.requests), self.scheme, placement)
-                with tr.span("resolve_executable", process=PROC_ENGINE,
-                             thread=thread, parent=d_span) as rs:
+                with self._phase("resolve_executable", d_span) as rs:
                     compiled, compile_s = self._executable(
                         bucket, launched_b, self.scheme)
                     fp_exec = None
@@ -435,12 +444,10 @@ class EngineCore:
                 # requests waiting on it (and its own compile_ms column) —
                 # never part of run_ms
                 batch_start = self.clock()
-                with tr.span("pad", process=PROC_ENGINE, thread=thread,
-                             parent=d_span):
+                with self._phase("pad", d_span):
                     inputs = self.workload.pad_inputs(
                         batch.requests, bucket, launched_b)
-                with tr.span("device_put", process=PROC_ENGINE,
-                             thread=thread, parent=d_span):
+                with self._phase("device_put", d_span):
                     inputs_j = tuple(jnp.asarray(a) for a in inputs)
                     params = self._params_for(placement)
                     if placement.sharded:
@@ -448,8 +455,7 @@ class EngineCore:
                         # lowered shardings
                         inputs_j = place_inputs(placement, *inputs_j)
                 real_tokens = sum(r.length for r in batch.requests)
-                with tr.span("launch", process=PROC_ENGINE, thread=thread,
-                             parent=d_span):
+                with self._phase("launch", d_span) as launch:
                     t_launch = time.perf_counter()
                     out = compiled(params, *inputs_j)  # async: no block
                     # the fidelity re-run launches behind the main forward
@@ -477,10 +483,7 @@ class EngineCore:
                 # the launched executable actually flattens
                 qmm_tokens=launched_b * bucket * bucket),
             occupancy=real_tokens / (launched_b * bucket),
-            seq=seq, thread=thread,
-            flight_span=tr.begin("in_flight", process=PROC_ENGINE,
-                                 thread=thread, batch_seq=seq,
-                                 bucket=bucket))
+            seq=seq, thread=thread, launched_at=launch.t_start)
         self._inflight.append(flight)
         self.metrics.record_dispatch(len(self._inflight),
                                      self.inflight_depth, flight.occupancy,
@@ -500,28 +503,25 @@ class EngineCore:
         flight = self._inflight.popleft()
         batch = flight.batch
         tr = self.tracer
-        if flight.flight_span is not None:   # device time is over once we
-            tr.end(flight.flight_span)       # start blocking on the result
         r_span = tr.begin("retire", process=PROC_ENGINE,
                           thread=flight.thread or f"batch-{flight.seq:04d}",
                           batch_seq=flight.seq, bucket=flight.bucket)
         try:
-            with annotate(f"serve.retire/{flight.bucket}"):
-                with tr.span("block", process=PROC_ENGINE,
-                             thread=flight.thread, parent=r_span):
+            with tr.annotate(f"serve.retire/{flight.bucket}", flight.seq):
+                with self._phase("block", r_span) as block:
                     self.workload.block_on(flight.out)
                 run_s = time.perf_counter() - flight.t_launch
-                with tr.span("transfer", process=PROC_ENGINE,
-                             thread=flight.thread, parent=r_span):
+                self._record_device(flight, block)
+                with self._phase("transfer", r_span):
                     # the workload owns the device->host move and any
                     # lazy-transfer policy (fold defers the distogram —
                     # the peak host-memory term at long N — behind a
                     # shared BatchDeviceOutput)
                     payload = self.workload.transfer(flight)
         except Exception as e:
-            tr.end(r_span, status="failed", error=repr(e))
+            tr.end(r_span, status="failed", error=repr(e), gc_s=gc_pause_s())
             raise BatchExecutionError(batch, e) from e
-        tr.end(r_span)
+        tr.end(r_span, gc_s=gc_pause_s())
         self.metrics.record_inflight(len(self._inflight))
         # live refinement: predict BEFORE observing (the EWMA would
         # otherwise be pulled toward the value it is judged against), then
@@ -541,6 +541,28 @@ class EngineCore:
         for r in results:
             self.metrics.record(r)
         return results
+
+    def _phase(self, name: str, parent: Span):
+        """A span of one host phase of a batch, on the batch's track under
+        ``parent``, with the profiler annotation ``serve.<name>/<bucket>``."""
+        return self.tracer.span(
+            name, process=PROC_ENGINE, thread=parent.thread, parent=parent,
+            annotation=f"serve.{name}/{parent.attrs['bucket']}")
+
+    def _record_device(self, flight: InFlightBatch, block: Span) -> None:
+        """The batch's ``device`` span: from the later of its launch and
+        the previous batch's ready time to its own ready time, when
+        ``block`` returned.  ``late`` marks a ``block`` under 1 ms: the host
+        came after the device had finished, so the end is an upper bound."""
+        ready = block.t_end
+        start = min(max(flight.launched_at, self._ready_at), ready)
+        flight.device_s = ready - start
+        self._ready_at = ready
+        tr = self.tracer
+        late = {"late": True} if block.duration < 1e-3 else {}
+        span = tr.begin("device", process=PROC_ENGINE, thread=block.thread,
+                        t=start, batch_seq=flight.seq, bucket=flight.bucket)
+        tr.end(span, t=ready, **late)
 
     def execute(self, batch: ScheduledBatch) -> list[FoldResult]:
         """Synchronous compat surface: dispatch + immediately retire.

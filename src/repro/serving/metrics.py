@@ -196,7 +196,13 @@ class EngineMetrics:
             "fold_queue_wait_seconds", "Submit-to-dispatch queue wait",
             ("bucket",))
         self._m_run = reg.histogram(
-            "fold_run_seconds", "Dispatch-to-retire batch latency",
+            "fold_run_seconds",
+            "Launch-to-ready batch latency, queued behind the previous "
+            "in-flight batch included",
+            ("bucket", "placement", "backend"))
+        self._m_device = reg.histogram(
+            "fold_device_seconds",
+            "Queue-free device time of the request's batch",
             ("bucket", "placement", "backend"))
         self._m_compiles = reg.counter(
             "fold_compiles_total", "Executable-cache misses (AOT compiles)",
@@ -264,6 +270,9 @@ class EngineMetrics:
             self._m_run.observe(r.run_ms / 1e3, bucket=r.bucket,
                                 placement=r.placement,
                                 backend=r.kernel_backend)
+            self._m_device.observe(r.device_ms / 1e3, bucket=r.bucket,
+                                   placement=r.placement,
+                                   backend=r.kernel_backend)
         with self._lock:
             self.results.append(r)
             st = self._buckets.setdefault(r.bucket, BucketStats(r.bucket))

@@ -1,11 +1,12 @@
 """Bridge from the serving tracer to the JAX/XLA profiler.
 
 The span tracer times *host-side* phases; the XLA profiler sees *device*
-kernels.  To line the two up, the engine core wraps its dispatch/retire
-bodies in ``annotate(...)`` — a ``jax.profiler.TraceAnnotation`` when the
-profiler API is available (a cheap no-op context otherwise), so a
-``--jax-profile DIR`` run shows the engine's batch phases as named ranges
-inside the XLA timeline, alongside the kernels they launched.
+kernels.  To line the two up, the Tracer opens ``annotate(...)`` — a
+``jax.profiler.TraceAnnotation`` when the profiler API is available (a
+cheap no-op context otherwise) — around the engine's batch phases, so a
+``--jax-profile DIR`` run shows them as named ranges inside the XLA
+timeline, alongside the kernels they launched.  The Tracer is its only
+caller (``Tracer.annotate``).
 
 ``jax_profile(dir)`` is the run-level context the launchers use: start a
 JAX profiler trace into ``dir`` (open with TensorBoard or Perfetto), stop
@@ -33,21 +34,13 @@ def _annotation_cls():
     return _ANNOTATION
 
 
-def annotate(name: str):
-    """Context manager naming the enclosed host work in XLA profiler
-    traces; a no-op context when the profiler API is unavailable."""
+def annotate(name: str, **stats):
+    """Context manager naming the enclosed host work ``name`` in XLA
+    profiler traces, with ``stats`` as the event's stats (the event keeps
+    its plain name); a no-op context when the profiler API is
+    unavailable."""
     cls = _annotation_cls()
-    return contextlib.nullcontext() if cls is None else cls(name)
-
-
-def step_annotation(name: str, step: int):
-    """``StepTraceAnnotation`` variant (profiler step markers); falls back
-    to a plain annotation, then to a no-op."""
-    try:
-        from jax.profiler import StepTraceAnnotation
-        return StepTraceAnnotation(name, step_num=step)
-    except Exception:
-        return annotate(f"{name}:{step}")
+    return contextlib.nullcontext() if cls is None else cls(name, **stats)
 
 
 @contextlib.contextmanager

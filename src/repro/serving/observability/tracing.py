@@ -6,11 +6,24 @@ client stamps arrivals/deadlines with, so spans and lifecycle telemetry
 can never disagree about when something happened).  Spans form trees via
 ``parent`` links; the client hangs a per-request tree off every
 ``FoldHandle`` (submit → admission → queued → running → terminal) and the
-engine core records per-batch trees (dispatch[resolve/pad/device_put/
-launch] → in_flight → retire[block/transfer]) on one track per batch —
-which is what makes the pipelined in-flight ring's overlap *visible*:
-batch k+1's dispatch span starting before batch k's retire span ends IS
-the pipelining story, as a queryable artifact.
+engine core records per-batch trees on one track per batch:
+dispatch[resolve_executable/pad/device_put/launch], device (the batch's
+queue-free device time), retire[block/transfer], and fetch (the
+distogram's device-to-host copy, when a consumer asks for it).  That is
+what makes the pipelined in-flight ring's overlap *visible*: batch k+1's
+dispatch span starting before batch k's retire span ends IS the
+pipelining story, as a queryable artifact.
+
+The Tracer is also the only writer of JAX profiler annotations
+(``annotate``, and ``span(..., annotation=...)``): each carries the
+batch's ``batch_seq`` and ``t``, the tracer clock at entry, as event
+stats, so a profile's event is joined to its span by batch and by
+``event start - t``, one offset for the whole profile.
+
+Garbage collection: the first Tracer installs one process-wide
+``gc.callbacks`` hook that adds every collection's pause to a cumulative
+counter (``gc_pause_s``) and hands each generation-2 collection to every
+live Tracer as a ``gc`` span on the ``engine/gc`` track.
 
 ``chrome_trace()`` exports the span set as Chrome-trace/Perfetto JSON
 (B/E duration events plus M metadata naming the tracks) — load the file
@@ -27,14 +40,64 @@ it dropped (``dropped``).
 from __future__ import annotations
 
 import dataclasses
+import gc
 import json
 import threading
 import time
-from typing import Any, Callable, IO, Iterator
+import weakref
+from collections import deque
+from typing import Any, Callable, IO
+
+from repro.serving.observability import profiler
 
 #: canonical track (process) names used across the serving stack
 PROC_REQUESTS = "requests"
 PROC_ENGINE = "engine"
+#: the track of generation-2 garbage collections (process ``engine``)
+GC_THREAD = "gc"
+
+
+class _GCWatch:
+    """The process-wide ``gc.callbacks`` hook.  ``pause_s`` sums the pause
+    of every collection, all generations; a generation-2 collection is
+    queued on every live Tracer, which records it as a ``gc`` span at its
+    next span (a collection can run while a Tracer holds its lock, so the
+    hook never takes it).  Generations 0 and 1 run hundreds of times a
+    second and would fill a Tracer's span cap: they only count."""
+
+    def __init__(self):
+        self.pause_s = 0.0
+        self._t0: float | None = None
+        self.tracers: weakref.WeakSet = weakref.WeakSet()
+
+    def watch(self, tracer: "Tracer") -> None:
+        if self not in gc.callbacks:
+            gc.callbacks.append(self)
+        self.tracers.add(tracer)
+
+    def __call__(self, phase: str, info: dict) -> None:
+        # collections never overlap, so only one hook call runs at a time
+        if phase == "start":
+            self._t0 = time.perf_counter()
+            return
+        if self._t0 is None:            # installed mid-collection
+            return
+        pause = time.perf_counter() - self._t0
+        self._t0 = None
+        self.pause_s += pause
+        if info["generation"] == 2:
+            for tr in list(self.tracers):
+                t = tr.clock()
+                tr._gc_pending.append((t - pause, t, info["collected"]))
+
+
+_GC = _GCWatch()
+
+
+def gc_pause_s() -> float:
+    """Seconds this process has spent in garbage collection since the
+    first Tracer was created (every generation)."""
+    return _GC.pause_s
 
 
 @dataclasses.dataclass
@@ -70,16 +133,22 @@ class Span:
 
 class _SpanScope:
     """Context manager yielded by ``Tracer.span`` — ends on exit, stamping
-    an ``error`` attr when the body raised."""
+    an ``error`` attr when the body raised; holds the span's profiler
+    annotation open over the body when it has one."""
 
-    def __init__(self, tracer: "Tracer", span: Span):
+    def __init__(self, tracer: "Tracer", span: Span, annotation):
         self._tracer = tracer
         self.span = span
+        self._annotation = annotation
 
     def __enter__(self) -> Span:
+        if self._annotation is not None:
+            self._annotation.__enter__()
         return self.span
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         attrs = {} if exc is None else {"error": repr(exc)}
         self._tracer.end(self.span, **attrs)
 
@@ -96,6 +165,9 @@ class Tracer:
         self.metadata: dict[str, Any] = {}   # exported at the trace root
         self._lock = threading.Lock()
         self._next_id = 1
+        # generation-2 collections the gc hook queued: (t0, t1, collected)
+        self._gc_pending: deque = deque()
+        _GC.watch(self)
 
     # -- recording --------------------------------------------------------
     def begin(self, name: str, *, process: str, thread: str,
@@ -104,11 +176,18 @@ class Tracer:
         """Open a span now (or at ``t`` on the tracer clock).  Past the
         ``max_spans`` cap the span is still returned (so callers need no
         None-guards) but not retained."""
+        if self._gc_pending:
+            self._record_gc()
         t = self.clock() if t is None else t
+        return self._add(name, process, thread,
+                         None if parent is None else parent.span_id, t,
+                         attrs)
+
+    def _add(self, name: str, process: str, thread: str,
+             parent_id: int | None, t: float, attrs: dict) -> Span:
         with self._lock:
-            span = Span(self._next_id,
-                        None if parent is None else parent.span_id,
-                        name, process, thread, t, attrs=dict(attrs))
+            span = Span(self._next_id, parent_id, name, process, thread, t,
+                        attrs=dict(attrs))
             self._next_id += 1
             if len(self.spans) >= self.max_spans:
                 self.dropped += 1
@@ -126,11 +205,40 @@ class Tracer:
             span.attrs.update(attrs)
 
     def span(self, name: str, *, process: str, thread: str,
-             parent: Span | None = None, **attrs) -> _SpanScope:
-        """``with tracer.span(...)`` — begins now, ends on exit."""
-        return _SpanScope(self, self.begin(name, process=process,
-                                           thread=thread, parent=parent,
-                                           **attrs))
+             parent: Span | None = None, annotation: str | None = None,
+             **attrs) -> _SpanScope:
+        """``with tracer.span(...)`` — begins now, ends on exit.  With
+        ``annotation``, the body also runs under that profiler annotation
+        (``annotate``), whose ``batch_seq`` is the span's or its
+        parent's."""
+        span = self.begin(name, process=process, thread=thread,
+                          parent=parent, **attrs)
+        ann = None
+        if annotation is not None:
+            seq = attrs.get("batch_seq", None if parent is None
+                            else parent.attrs.get("batch_seq"))
+            ann = self.annotate(annotation, seq)
+        return _SpanScope(self, span, ann)
+
+    def annotate(self, name: str, batch_seq: int | None = None):
+        """A JAX profiler annotation ``name`` around host work (a no-op
+        context without the profiler API), carrying ``batch_seq`` and
+        ``t``, this tracer's clock now, as event stats: a profile's event
+        then joins its span by batch, and its start less ``t`` is one
+        offset for the whole profile."""
+        stats = {} if batch_seq is None else {"batch_seq": batch_seq}
+        return profiler.annotate(name, **stats, t=self.clock())
+
+    def _record_gc(self) -> None:
+        """Record the collections the gc hook queued as ``gc`` spans."""
+        while True:
+            try:
+                t0, t1, collected = self._gc_pending.popleft()
+            except IndexError:          # drained, here or by another thread
+                return
+            span = self._add("gc", PROC_ENGINE, GC_THREAD, None, t0,
+                             {"generation": 2, "collected": collected})
+            self.end(span, t=t1)
 
     def instant(self, name: str, *, process: str, thread: str,
                 **attrs) -> Span:
@@ -148,10 +256,13 @@ class Tracer:
         with self._lock:
             self.spans = []
             self.dropped = 0
+            self._gc_pending.clear()
 
     # -- queries ----------------------------------------------------------
     def find(self, name: str | None = None, *, process: str | None = None,
              thread: str | None = None) -> list[Span]:
+        if self._gc_pending:
+            self._record_gc()
         with self._lock:
             spans = list(self.spans)
         return [s for s in spans
@@ -168,8 +279,10 @@ class Tracer:
         timestamp and stamped ``truncated`` — every B gets its E.  Child
         intervals are clamped into their parent (and siblings serialized)
         so the per-track event stream always nests, whatever the recorded
-        floats did at µs granularity.
+        floats did at µs granularity; roots that overlap go to lanes.
         """
+        if self._gc_pending:
+            self._record_gc()
         with self._lock:
             spans = list(self.spans)
             metadata = dict(self.metadata)
@@ -184,31 +297,39 @@ class Tracer:
         pids: dict[str, int] = {}
         tids: dict[tuple[str, str], int] = {}
         events: list[dict] = []
-        for s in spans:
-            if s.process not in pids:
-                pids[s.process] = len(pids) + 1
+
+        def tid_of(process: str, thread: str) -> int:
+            if process not in pids:
+                pids[process] = len(pids) + 1
                 events.append({"ph": "M", "name": "process_name",
-                               "pid": pids[s.process], "tid": 0,
-                               "args": {"name": s.process}})
-            track = (s.process, s.thread)
-            if track not in tids:
-                tids[track] = len(tids) + 1
+                               "pid": pids[process], "tid": 0,
+                               "args": {"name": process}})
+            if (process, thread) not in tids:
+                tids[process, thread] = len(tids) + 1
                 events.append({"ph": "M", "name": "thread_name",
-                               "pid": pids[s.process], "tid": tids[track],
-                               "args": {"name": s.thread}})
+                               "pid": pids[process],
+                               "tid": tids[process, thread],
+                               "args": {"name": thread}})
+            return tids[process, thread]
+
+        for s in spans:
+            tid_of(s.process, s.thread)
 
         def us(t: float) -> float:
             return (t - epoch) * 1e6
 
         # per-track DFS emission: children clamped into parents, siblings
-        # serialized — the emitted B/E sequence per track always balances
+        # serialized — the emitted B/E sequence per track always balances.
+        # A root that overlaps an earlier root of its track (a batch's
+        # ``device`` span spans the end of its dispatch and the start of
+        # its retire) goes to a lane of its own, ``<thread>.<lane>``
         by_track: dict[tuple[str, str], list[Span]] = {}
         for s in spans:
             by_track.setdefault((s.process, s.thread), []).append(s)
         ids_by_track = {track: {s.span_id for s in ss}
                         for track, ss in by_track.items()}
         for track, ss in sorted(by_track.items()):
-            pid, tid = pids[track[0]], tids[track]
+            pid = pids[track[0]]
             kids: dict[int | None, list[Span]] = {}
             for s in ss:
                 # a parent on another track (or dropped) makes this a root
@@ -216,7 +337,7 @@ class Tracer:
                           if s.parent_id in ids_by_track[track] else None)
                 kids.setdefault(parent, []).append(s)
 
-            def emit(s: Span, lo: float, hi: float) -> float:
+            def emit(s: Span, tid: int, lo: float, hi: float) -> float:
                 t0 = min(max(s.t_start, lo), hi)
                 t1 = hi if s.t_end is None else min(max(s.t_end, t0), hi)
                 args = dict(s.attrs)
@@ -227,15 +348,21 @@ class Tracer:
                 cursor = t0
                 for child in sorted(kids.get(s.span_id, ()),
                                     key=lambda c: (c.t_start, c.span_id)):
-                    cursor = emit(child, cursor, t1)
+                    cursor = emit(child, tid, cursor, t1)
                 events.append({"ph": "E", "name": s.name, "pid": pid,
                                "tid": tid, "ts": us(t1)})
                 return t1
 
-            cursor = epoch
+            lanes: list[float] = []         # where each lane's last root ended
             for root in sorted(kids.get(None, ()),
                                key=lambda s: (s.t_start, s.span_id)):
-                cursor = emit(root, cursor, horizon)
+                lane = next((i for i, end in enumerate(lanes)
+                             if root.t_start >= end), len(lanes))
+                if lane == len(lanes):
+                    lanes.append(epoch)
+                tid = tid_of(track[0], track[1] if lane == 0
+                             else f"{track[1]}.{lane}")
+                lanes[lane] = emit(root, tid, lanes[lane], horizon)
         # one global timeline: stable sort keeps each track's DFS order
         events.sort(key=lambda e: e.get("ts", -1.0))
         return {"traceEvents": events, "displayTimeUnit": "ms",
@@ -358,8 +485,3 @@ def span_tree(spans: list[Span]) -> list[dict]:
     roots = sorted(kids.get(None, ()), key=lambda s: (s.t_start, s.span_id))
     return [build(r) for r in roots]
 
-
-def iter_tree(tree: list[dict]) -> Iterator[Span]:
-    for node in tree:
-        yield node["span"]
-        yield from iter_tree(node["children"])

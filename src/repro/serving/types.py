@@ -13,9 +13,10 @@ submit and batch start would make queue_wait_ms negative.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
-from typing import Any
+from typing import Any, Callable
 
 import numpy as np
 
@@ -28,17 +29,21 @@ class BatchDeviceOutput:
     tiny XLA program per distinct index/length and pollute the engine's
     zero-recompile steady state) and then drops the device reference so the
     device buffer can be freed.  Thread-safe: the background driver may
-    retire batches while a consumer fetches on another thread.
+    retire batches while a consumer fetches on another thread.  ``span``,
+    when given, makes the context that times that one transfer (the
+    engine's ``fetch`` span).
     """
 
     def __init__(self, device_array: Any, nbytes: int = 0,
-                 on_release: Any = None):
+                 on_release: Any = None,
+                 span: Callable[[], Any] | None = None):
         self._device = device_array
         self._host: np.ndarray | None = None
         self._lock = threading.Lock()
         #: device bytes this output pins until first host() (telemetry)
         self.nbytes = int(nbytes)
         self._on_release = on_release
+        self._span = span or contextlib.nullcontext
 
     @property
     def materialized(self) -> bool:
@@ -48,7 +53,8 @@ class BatchDeviceOutput:
         release = None
         with self._lock:
             if self._host is None:
-                self._host = np.asarray(self._device)
+                with self._span():
+                    self._host = np.asarray(self._device)
                 self._device = None          # release the device buffer
                 release, self._on_release = self._on_release, None
             host = self._host
@@ -197,6 +203,11 @@ class FoldResult:
                                        # inflight_depth > 1 this includes
                                        # time queued behind the previous
                                        # in-flight batch on the device
+    device_ms: float = 0.0             # the batch's queue-free device time:
+                                       # from the later of its launch and
+                                       # the previous batch's ready time to
+                                       # its ready time (an upper bound
+                                       # when the host came late)
     launched_batch: int = 0            # rows the executable actually ran
                                        # (>= batch_size; dummy rows only
                                        # when a cached size was reused)

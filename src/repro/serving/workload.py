@@ -46,6 +46,7 @@ transport code is shared unchanged.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import TYPE_CHECKING, Any
 
 import jax
@@ -56,6 +57,7 @@ from repro.models.ppm import ppm_forward, tm_score
 from repro.models.ppm.trunk import CHUNKED_ATTN_LEN
 from repro.serving.admission import AdmissionController
 from repro.serving.metrics import EngineMetrics
+from repro.serving.observability.tracing import PROC_ENGINE
 from repro.serving.types import (BatchDeviceOutput, FoldResult,
                                  LazyDistogram, pad_to_bucket)
 
@@ -201,7 +203,12 @@ class FoldWorkload(Workload):
             disto = BatchDeviceOutput(
                 darr, nbytes=pinned,
                 on_release=(lambda m=metrics, n=pinned:
-                            m.record_pinned(-n)))
+                            m.record_pinned(-n)),
+                span=partial(core.tracer.span, "fetch",
+                             process=PROC_ENGINE, thread=flight.thread,
+                             annotation=f"serve.fetch/{flight.bucket}",
+                             batch_seq=flight.seq, bucket=flight.bucket,
+                             bytes=pinned))
         fp_coords = (None if flight.fp_out is None
                      else np.asarray(flight.fp_out["coords"]))
         return coords_host, disto, fp_coords
@@ -231,6 +238,7 @@ class FoldWorkload(Workload):
                 queue_wait_ms=(flight.batch_start - req.arrival_time) * 1e3,
                 compile_ms=flight.compile_s * 1e3,
                 run_ms=run_s * 1e3,
+                device_ms=flight.device_s * 1e3,
                 launched_batch=flight.launched_b,
                 occupancy=flight.occupancy,
                 est_activation_bytes=flight.est,

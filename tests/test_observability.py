@@ -2,24 +2,31 @@
 over the request lifecycle, Chrome-trace/Perfetto export validity, the
 pipeline-overlap invariant at depth 2, metrics-registry thread safety and
 Prometheus exposition, the bounded driver-error ring, the compile-watch
-epoch, pinned-distogram accounting, and bench provenance.
+epoch, pinned-distogram accounting, and bench provenance; the per-batch
+device, fetch and gc spans and the profiler annotations' join stats.
 """
+import gc
+import glob
 import json
 import threading
 
 import jax
+import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs import reduce_ppm_config
 from repro.core import make_scheme
 from repro.models.ppm import init_ppm
 from repro.serving import (CompileWatcher, EngineCore, FoldClient,
-                           MetricsRegistry, MetricsServer,
+                           FoldRequest, FoldWorkload, MetricsRegistry,
+                           MetricsServer, ScheduledBatch,
                            pipeline_overlaps, reset_compile_watch,
                            validate_chrome_trace)
 from repro.serving import metrics as metrics_mod
-from repro.serving.observability.tracing import (PROC_ENGINE, PROC_REQUESTS,
-                                                 Tracer, span_tree)
+from repro.serving.observability.tracing import (GC_THREAD, PROC_ENGINE,
+                                                 PROC_REQUESTS, Tracer,
+                                                 gc_pause_s, span_tree)
 
 CFG = reduce_ppm_config()
 PARAMS = init_ppm(jax.random.PRNGKey(0), CFG)
@@ -98,10 +105,200 @@ def test_engine_batch_span_tree():
     rk = {s.name for s in tr.find(process=PROC_ENGINE)
           if s.parent_id == retires[0].span_id}
     assert rk == {"block", "transfer"}
-    # in_flight bridges dispatch end -> retire start on the same track
-    [fl] = tr.find("in_flight", thread=d.thread)
-    assert fl.t_start >= d.t_end and fl.t_end is not None
-    assert fl.t_end <= retires[0].t_start + 1e-9
+    # device runs on the same track from the launch (no batch before it)
+    # to the end of the block, when the outputs were ready
+    [launch] = [s for s in tr.find("launch") if s.parent_id == d.span_id]
+    [block] = [s for s in tr.find("block")
+               if s.parent_id == retires[0].span_id]
+    [dev] = tr.find("device", thread=d.thread)
+    assert dev.t_start == launch.t_start and dev.t_end == block.t_end
+    assert dev.attrs["batch_seq"] == d.attrs["batch_seq"]
+
+
+# -- device, fetch and gc spans; profiler annotations -----------------------
+class _StubFold(FoldWorkload):
+    """The fold workload with a forward that compiles in no time; each
+    ``block`` takes the next of ``block_s`` on ``clock``."""
+
+    def __init__(self, clock=None, block_s=()):
+        super().__init__()
+        self.clock = clock
+        self.block_s = list(block_s)
+
+    def forward(self, scheme, chunk, params, aatype, mask):
+        b, n = aatype.shape
+        return {"coords": jnp.broadcast_to(aatype[..., None], (b, n, 3))
+                .astype(jnp.float32),
+                "distogram": jnp.broadcast_to(mask[:, :, None, None],
+                                              (b, n, n, 4))
+                .astype(jnp.float32)}
+
+    def block_on(self, out):
+        super().block_on(out)
+        if self.block_s:
+            self.clock.advance(self.block_s.pop(0))
+
+
+def _stub_core(**kw) -> EngineCore:
+    return EngineCore(PARAMS, CFG, SCHEME, buckets=(32,),
+                      max_tokens_per_batch=64, max_batch=2,
+                      inflight_depth=2, **kw)
+
+
+def _batch(n_requests: int, first_id: int) -> ScheduledBatch:
+    return ScheduledBatch(32, tuple(FoldRequest(first_id + i, _seq(24))
+                                    for i in range(n_requests)), 0)
+
+
+def test_device_span_starts_at_launch_or_previous_ready():
+    clock = ManualClock()
+    core = _stub_core(clock=clock,
+                      workload=_StubFold(clock, block_s=(1.0, 0.8, 0.0)))
+    core.dispatch(_batch(1, 0))              # launched at 1000.0
+    clock.advance(0.5)
+    core.dispatch(_batch(1, 1))              # launched at 1000.5
+    [r0] = core.retire()                     # ready at 1001.5
+    [r1] = core.retire()                     # ready at 1002.3
+    core.dispatch(_batch(1, 2))              # launched at 1002.3
+    clock.advance(2.0)                       # the host is away ...
+    [r2] = core.retire()                     # ... block returns at once
+    devs = sorted(core.tracer.find("device", process=PROC_ENGINE),
+                  key=lambda s: s.attrs["batch_seq"])
+    assert [(s.t_start, s.t_end) for s in devs] == pytest.approx(
+        [(1000.0, 1001.5), (1001.5, 1002.3), (1002.3, 1004.3)])
+    # batch 1 waited behind batch 0 from its launch at 1000.5 to 1001.5
+    assert [s.attrs.get("late") for s in devs] == [None, None, True]
+    assert [d.thread for d in devs] == ["batch-0000", "batch-0001",
+                                        "batch-0002"]
+    assert [r.device_ms for r in (r0, r1, r2)] == pytest.approx(
+        [1500.0, 800.0, 2000.0])
+    assert r1.run_ms >= 0.0                  # still perf_counter launch
+    hist = core.metrics.registry.get("fold_device_seconds")
+    assert hist.count(bucket=32, placement="single",
+                      backend=r0.kernel_backend) == 3
+    # the export puts the device span, which overlaps dispatch and
+    # retire, on a lane of its own; retire keeps its block
+    trace = core.tracer.chrome_trace()
+    validate_chrome_trace(trace)
+    lanes = {e["args"]["name"]: e["tid"] for e in trace["traceEvents"]
+             if e["ph"] == "M" and e["name"] == "thread_name"}
+    on_lane = {e["name"] for e in trace["traceEvents"]
+               if e["ph"] == "B" and e["tid"] == lanes["batch-0001.1"]}
+    assert on_lane == {"device"}
+    block = [e for e in trace["traceEvents"] if e["ph"] in "BE"
+             and e["name"] == "block" and e["tid"] == lanes["batch-0001"]]
+    assert block[1]["ts"] - block[0]["ts"] == pytest.approx(0.8e6)
+
+
+def test_fetch_span_once_per_batch_with_bytes():
+    core = _stub_core(workload=_StubFold())
+    core.dispatch(_batch(2, 0))
+    results = core.retire()
+    for r in results + results:               # both rows, twice
+        np.asarray(r.distogram)
+    [fetch] = core.tracer.find("fetch", process=PROC_ENGINE)
+    assert fetch.thread == "batch-0000" and fetch.parent_id is None
+    assert fetch.attrs["batch_seq"] == 0 and fetch.attrs["bucket"] == 32
+    assert fetch.attrs["bytes"] == 2 * 32 * 32 * 4 * 4    # float32
+    assert fetch.t_end is not None
+
+
+def test_gc_collect_counts_and_records_one_gen2_span():
+    tr = Tracer()
+    gc.disable()                   # no automatic collection in between
+    try:
+        before = gc_pause_s()
+        gc.collect()
+        after = gc_pause_s()
+    finally:
+        gc.enable()
+    assert after > before
+    [span] = tr.find("gc", process=PROC_ENGINE, thread=GC_THREAD)
+    assert span.attrs["generation"] == 2
+    assert span.attrs["collected"] >= 0
+    assert 0.0 <= span.duration <= after - before + 1e-9
+    # a dispatch and a retire stamp the counter
+    core = _stub_core(workload=_StubFold())
+    core.dispatch(_batch(1, 0))
+    core.retire()
+    [d] = core.tracer.find("dispatch")
+    [r] = core.tracer.find("retire")
+    assert after <= d.attrs["gc_s"] <= r.attrs["gc_s"]
+
+
+def test_gc_spans_under_threads_lose_none():
+    """Collections forced from several threads while others record spans:
+    the hook never takes a Tracer's lock (a collection can start while a
+    thread holds it), and every generation-2 collection becomes one span."""
+    import sys
+    tr = Tracer()
+    seen = []
+
+    def count(phase, info):
+        if phase == "stop" and info["generation"] == 2:
+            seen.append(1)
+
+    def spans():
+        for _ in range(2000):
+            tr.end(tr.begin("s", process=PROC_REQUESTS, thread="t",
+                            junk=[{} for _ in range(5)]))
+
+    def collect():
+        for _ in range(30):
+            gc.collect()
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    gc.callbacks.append(count)
+    try:
+        threads = [threading.Thread(target=f)
+                   for f in (spans, spans, spans, collect, collect)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        gc.callbacks.remove(count)
+        sys.setswitchinterval(interval)
+    assert len(tr.find("gc", thread=GC_THREAD)) == len(seen) >= 30
+
+
+def test_profiler_annotations_carry_batch_seq_and_tracer_clock(tmp_path):
+    """Under the JAX profiler on the CPU, the engine's annotations keep
+    their plain names and carry ``batch_seq`` and ``t`` (the tracer clock
+    at entry): ``start - t`` is one offset for the whole profile."""
+    core = _stub_core(workload=_StubFold())
+    core.dispatch(_batch(1, 0))        # compile outside the profile
+    core.retire()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for i in range(3):
+            core.dispatch(_batch(1, 1 + i))
+            if core.inflight_full:
+                core.retire()
+        while core.inflight_count:
+            for r in core.retire():
+                np.asarray(r.distogram)
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    events = [e for plane in data.planes if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events
+              if e.name.startswith("serve.")]
+    outer = [e for e in events if e.name in ("serve.dispatch/32",
+                                             "serve.retire/32")]
+    assert len(outer) == 6
+    offsets = []
+    for e in events:
+        stats = dict(e.stats)
+        assert {"batch_seq", "t"} <= set(stats), e.name
+        offsets.append(e.start_ns * 1e-9 - stats["t"])
+    assert {dict(e.stats)["batch_seq"] for e in outer} == {1, 2, 3}
+    assert max(offsets) - min(offsets) < 1e-3
+    names = {e.name for e in events}
+    assert {"serve.block/32", "serve.launch/32", "serve.fetch/32"} <= names
 
 
 # -- terminal closure: cancel / expiry / rejection / failure ----------------
