@@ -40,7 +40,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.qmatmul import qmatmul_fused_ref
 from repro.kernels.aaq_matmul.ops import aaq_linear
-from repro.kernels.flash_attention.flash_attention import flash_mha_pallas
+from repro.kernels.flash_attention.flash_attention import (flash_mha_pallas,
+                                                          plan as flash_plan)
 from repro.kernels.flash_attention.ref import mha_chunked
 from repro.parallel.sharding import KERNEL_MESH, MODEL, rule_value
 
@@ -115,8 +116,7 @@ def floors_source() -> str:
 # interpret-mode block override: the interpreter executes the grid serially
 # with a large fixed per-step overhead, so correctness-path runs want the
 # fewest, fattest blocks (VMEM limits don't apply off-chip); compiled TPU
-# runs keep the MXU-aligned 128/256 defaults
-INTERP_BLOCK_SEQ = 1024      # flash block_q/block_k cap
+# runs keep the MXU-aligned 256 defaults
 INTERP_BLOCK_T = 4096        # aaq quant/matmul token-block cap
 INTERP_BLOCK_D = 1024        # aaq matmul output-block cap
 
@@ -124,6 +124,10 @@ _MODE = AUTO
 
 counters: dict[str, int] = {
     "attention.pallas": 0,
+    # which form each Pallas attention call took: the whole key range in
+    # one block (one KV step) or the key range streamed in blocks
+    "attention.pallas.kv_resident": 0,
+    "attention.pallas.kv_streamed": 0,
     "attention.ref": 0,
     "qmatmul.pallas": 0,
     "qmatmul.ref": 0,
@@ -263,28 +267,29 @@ def _split(*sizes):
 
 def attention(q, k, v, *, bias=None, causal=False, window=None,
               kv_valid_len=None, softmax_scale=None, q_chunk=512,
-              block_q=128, block_k=128, backend=None):
+              backend=None):
     """Token-wise MHA: q (B,Sq,Hq,D); k,v (B,Skv,Hkv,D); bias (Bb,Hq,Sq,Skv)
     with block batch-broadcast (bias row t covers B//Bb consecutive q rows).
 
-    Pallas path: the fused flash kernel (interpret mode off-TPU).  Ref
+    Pallas path: the fused flash kernel (interpret mode off-TPU), which
+    sizes its blocks from the shapes each device sees; the
+    ``attention.pallas.kv_*`` counters say which form they gave.  Ref
     path: ``mha_chunked`` — bitwise the pre-dispatch model numerics.
     """
     be = resolve_attention(q.shape[1], k.shape[1], backend=backend)
     if be == PALLAS:
         counters["attention.pallas"] += 1
         interp = interpret_mode()
-        if interp:
-            block_q = max(block_q, min(q.shape[1], INTERP_BLOCK_SEQ))
-            block_k = max(block_k, min(k.shape[1], INTERP_BLOCK_SEQ))
         has_bias, has_kvl = bias is not None, kv_valid_len is not None
 
         def call(q, k, v, *rest):
+            b = rest[0] if has_bias else None
+            form = flash_plan(q, k, b, causal=causal, window=window)
+            counters["attention.pallas.kv_resident" if form.kv_resident
+                     else "attention.pallas.kv_streamed"] += 1
             return flash_mha_pallas(
-                q, k, v, rest[0] if has_bias else None,
-                rest[-1] if has_kvl else None, causal=causal, window=window,
-                softmax_scale=softmax_scale, block_q=block_q,
-                block_k=block_k, interpret=interp)
+                q, k, v, b, rest[-1] if has_kvl else None, causal=causal,
+                window=window, softmax_scale=softmax_scale, interpret=interp)
 
         h = _split(q.shape[2], k.shape[2])              # heads are independent
         args, specs = [q, k, v], [P(None, None, h, None)] * 3

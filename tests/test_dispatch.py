@@ -4,11 +4,14 @@ addressing), and the Pallas<->ref parity suite on the full PPM forward —
 {pallas-interpret, ref} x {fp32, AAQ} x B in {1,2} x N in {64, 300}.
 N=300 exercises the chunked token-wise path (>= CHUNKED_ATTN_LEN), N=64
 the explicit-pallas routing below the chunk threshold."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import esmfold_ppm
 from repro.core import make_scheme, quantize
 from repro.core.schemes import FP16Baseline
 from repro.kernels import dispatch
@@ -101,6 +104,33 @@ def test_counters_count_traces_not_executions():
         assert dispatch.counters["attention.ref"] == 1
         f(q)   # executable-cache hit: no new trace, no new count
         assert dispatch.counters["attention.ref"] == 1
+
+
+@pytest.mark.parametrize("n,chunk", [(256, None), (1024, 256)])
+def test_served_folds_hold_the_key_range_resident(n, chunk):
+    """At esmfold_ppm widths a bucketed fold (N=256) and a chunked long
+    fold (N=1,024, 256-row chunks) trace every Pallas attention call (the
+    triangular, sequence and structure-module ones) in the form with the
+    whole key range in one block; a causal LM call streams its keys."""
+    cfg = dataclasses.replace(esmfold_ppm.CONFIG, blocks=1)
+    params = jax.eval_shape(lambda: init_ppm(jax.random.PRNGKey(0), cfg))
+    aat = jax.ShapeDtypeStruct((2, n), jnp.int32)
+    mask = jax.ShapeDtypeStruct((2, n), jnp.bool_)
+    with dispatch.use_backend(dispatch.PALLAS):
+        dispatch.reset_counters()
+        jax.eval_shape(lambda p, a, m: ppm_forward(
+            p, a, cfg, FP16Baseline(), mask=m, chunk_size=chunk),
+            params, aat, mask)
+        c = dict(dispatch.counters)
+        assert c["attention.pallas"] >= 3
+        assert c["attention.pallas.kv_resident"] == c["attention.pallas"]
+        assert c["attention.pallas.kv_streamed"] == 0
+
+        dispatch.reset_counters()
+        q = jax.ShapeDtypeStruct((1, n, 4, 32), jnp.bfloat16)
+        jax.eval_shape(lambda a: dispatch.attention(a, a, a, causal=True), q)
+        assert dispatch.counters["attention.pallas.kv_streamed"] == 1
+        assert dispatch.counters["attention.pallas.kv_resident"] == 0
 
 
 @pytest.mark.parametrize("bits,k", [(8, 4), (4, 4), (4, 0)])

@@ -73,6 +73,25 @@ FLASH_CASES = [
          bias=False, kvlen=True),
     dict(B=2, Sq=33, Skv=70, Hq=2, Hkv=2, D=32, causal=False, window=None,
          bias=False, kvlen=True),
+    # triangular attention: B = proteins x rows, a (proteins, H, N, N) bias
+    # broadcast block-wise, per-row key lengths, H*D = 128, and the blocks
+    # the kernel plans itself (the whole key range in one block)
+    dict(B=48, Sq=24, Skv=24, Hq=4, Hkv=4, D=32, causal=False, window=None,
+         bias=2, kvlen=True, block=None),
+    # rows not a multiple of the rows per step (the rows are padded)
+    dict(B=131, Sq=16, Skv=16, Hq=4, Hkv=4, D=32, causal=False, window=None,
+         bias=1, kvlen=True, block=None),
+    # N not a multiple of 128 (q and keys padded to 256)
+    dict(B=6, Sq=200, Skv=200, Hq=4, Hkv=4, D=32, causal=False, window=None,
+         bias=3, kvlen=True, block=None),
+    # many rows a step with the key range streamed in blocks
+    dict(B=48, Sq=24, Skv=24, Hq=4, Hkv=4, D=32, causal=False, window=None,
+         bias=2, kvlen=True, block=(16, 8)),
+    # the sequence shape: many heads, a per-row bias
+    dict(B=2, Sq=64, Skv=64, Hq=16, Hkv=16, D=32, causal=False, window=None,
+         bias=2, kvlen=False, block=None),
+    dict(B=1, Sq=136, Skv=136, Hq=32, Hkv=32, D=32, causal=False,
+         window=None, bias=1, kvlen=True, block=None),
 ]
 
 
@@ -84,12 +103,14 @@ def test_flash_kernel_vs_ref(case, dtype):
     q = r((c["B"], c["Sq"], c["Hq"], c["D"]), 1)
     k = r((c["B"], c["Skv"], c["Hkv"], c["D"]), 2)
     v = r((c["B"], c["Skv"], c["Hkv"], c["D"]), 3)
-    bias = r((1, c["Hq"], c["Sq"], c["Skv"]), 4) if c["bias"] else None
-    kvlen = (jnp.array([c["Skv"] // 2, c["Skv"]] * (c["B"] // 2), jnp.int32)
-             if c["kvlen"] else None)
+    bias = (r((int(c["bias"]), c["Hq"], c["Sq"], c["Skv"]), 4)
+            if c["bias"] else None)
+    kvlen = (jnp.where(jnp.arange(c["B"]) % 2 == 0, c["Skv"] // 2, c["Skv"])
+             .astype(jnp.int32) if c["kvlen"] else None)
+    block_q, block_k = c.get("block", (32, 32)) or (None, None)
     o_k = flash_mha_pallas(q, k, v, bias, kvlen, causal=c["causal"],
-                           window=c["window"], block_q=32, block_k=32,
-                           interpret=True)
+                           window=c["window"], block_q=block_q,
+                           block_k=block_k, interpret=True)
     o_r = mha_ref(q, k, v, bias=bias, causal=c["causal"], window=c["window"],
                   kv_valid_len=kvlen)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2

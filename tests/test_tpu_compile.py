@@ -49,10 +49,11 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-def _flash(sharding, n, heads, dh, with_bias):
-    """Triangular (rows as batch, bias + kv length) or sequence attention."""
+def _flash(sharding, n, heads, dh, with_bias, rows=None):
+    """Triangular (``rows`` rows of one protein as batch, default ``n``; a
+    bf16 bias + kv length) or sequence attention (one protein, f32 bias)."""
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
-    b = n if with_bias else 1
+    b = (rows or n) if with_bias else 1
     qkv = [s((b, n, heads, dh), BF16) for _ in range(3)]
     if with_bias:
         return (functools.partial(flash_mha_pallas, interpret=False),
@@ -81,6 +82,11 @@ def _matmul(sharding, bits, k):
 CASES = {
     "flash-tri-N256-H4-D32-bias": lambda sh: _flash(sh, 256, 4, 32, True),
     "flash-seq-N512-H16-D64": lambda sh: _flash(sh, 512, 16, 64, False),
+    # the served shapes: a long fold's chunk of 256 rows at N=1,024, and
+    # its sequence attention (32 heads of 32, an f32 bias per protein)
+    "flash-tri-chunk256-N1024-H4-D32-bias":
+        lambda sh: _flash(sh, 1024, 4, 32, True, rows=256),
+    "flash-seq-N1024-H32-D32": lambda sh: _flash(sh, 1024, 32, 32, False),
     "quant-groupA-int8-k4": lambda sh: _quant(sh, 8, 4),
     "quant-groupB-int4-k4": lambda sh: _quant(sh, 4, 4),
     "quant-groupC-int4-k0": lambda sh: _quant(sh, 4, 0),
@@ -95,3 +101,32 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
     fn, args = CASES[case](one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_custom_call_reads_as_the_roofline_counts_it(one_chip,
+                                                           no_persistent_cache):
+    """The compiled long-chunk call, as the device trace names it, is
+    attributed to ``flash_mha_pallas`` and its work read at the shapes it
+    ran with: 4 B H S T D operations and each operand's bytes once.  A
+    layout change that the roofline's parser would misread fails here."""
+    from jax._src.lib import xla_client
+
+    from bench.ops.kernels import flash_call
+    from bench.trace import kernel_of
+
+    rows, n, heads, dh = 256, 1024, 4, 32
+    fn, args = _flash(one_chip, n, heads, dh, True, rows=rows)
+    compiled = jax.jit(fn).lower(*args).compile()
+    opts = xla_client._xla.HloPrintOptions.short_parsable()
+    opts.print_operand_shape = True
+    opts.print_percent = True
+    text = "\n".join(m.to_string(opts)
+                     for m in compiled.runtime_executable().hlo_modules())
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line and " = " in line]
+    assert len(calls) == 1
+    assert kernel_of(calls[0].partition(" = ")[0]) == "flash_mha_pallas"
+    call = flash_call(calls[0])
+    assert call.ops == 4 * rows * heads * n * n * dh
+    qkvo = 4 * rows * n * heads * dh * 2
+    assert call.bytes == qkvo + heads * n * n * 2 + rows * 4
