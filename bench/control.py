@@ -4,9 +4,10 @@
 
 For each seed it makes the cell's weights and backlog as a run does, folds a
 sample of the backlog's first batches (the longest and ``sample - 1``
-others) with the plain reference in float32 and again in float8 (e4m3),
-the precision below the configuration's bfloat16, and prints each seed's
-numbers (``bench/correct.py``) as one JSON line.  A limit holds only if the
+others) with the model's plain reference (``bench/models/<model>.py``) in
+float32 and again in float8 (e4m3), the precision below the
+configuration's bfloat16, and prints each seed's numbers
+(``bench/correct.py``) as one JSON line.  A limit holds only if the
 control's numbers break it.  Benchmark runs never run this; it runs on the
 chip at the cell's own size, and ``bench/test_bench_correct.py`` runs it at
 a test size.
@@ -23,7 +24,7 @@ CONTROL_DTYPE = "float8_e4m3fn"
 
 
 def backlog_sample(traffic: dict, seed: int, batches: int = 8):
-    """(sequence, bucket) of the folds a run's sample would draw: from the
+    """(request, bucket) of the folds a run's sample would draw: from the
     backlog's first ``batches`` batches, at the launch sizes the token
     budget gives."""
     from bench import correct
@@ -31,15 +32,17 @@ def backlog_sample(traffic: dict, seed: int, batches: int = 8):
     s = traffic["serve"]
     caps = {b: min(s["max_batch"], max(1, s["max_tokens_per_batch"] // b))
             for b in traffic["buckets"]}
-    backlog = traffic_generator(traffic).Backlog(traffic, seed, caps)
-    seqs, rows = [], []
+    gen = traffic_generator(traffic)
+    backlog = gen.Backlog(traffic, seed, caps)
+    requests, rows = [], []
     for _ in range(batches):
         batch = backlog.next_batch()[1]
-        rows.append([Fold(len(seqs) + i, len(q), True)
+        rows.append([Fold(len(requests) + i, gen.length(q), True)
                      for i, q in enumerate(batch)])
-        seqs += batch
+        requests += batch
     buckets = traffic["buckets"]
-    return [(seqs[f.request_id], next(b for b in buckets if f.length <= b))
+    return [(requests[f.request_id],
+             next(b for b in buckets if f.length <= b))
             for f in correct.sample(rows, seed, traffic["sample"])]
 
 
@@ -48,14 +51,14 @@ def control_readings(cfg: dict, traffic: dict, seed: int,
     """The numbers of the control (the reference in ``dtype``) against the
     float32 reference, worst over the sample."""
     from bench import correct
-    from bench.reference.ppm import Reference
-    from bench.weights import make_params
-    params = make_params(cfg, seed)
-    ref, ctl = Reference(cfg), Reference(cfg, dtype)
+    from bench.run import model_of
+    model = model_of(cfg)
+    params = model.make_params(cfg, seed)
+    ref, ctl = model.Reference(cfg), model.Reference(cfg, dtype)
     per_fold = []
-    for aatype, bucket in backlog_sample(traffic, seed):
-        rc, rd = ref(params, aatype, bucket)
-        cc, cd = ctl(params, aatype, bucket)
+    for request, bucket in backlog_sample(traffic, seed):
+        rc, rd = ref(params, request, bucket)
+        cc, cd = ctl(params, request, bucket)
         per_fold.append(correct.readings(cc, cd, rc, rd))
     return correct.worst(per_fold)
 

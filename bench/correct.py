@@ -2,10 +2,10 @@
 
 Once the window has closed, a sample of the folds the server finished is
 drawn from the seed: the longest, the last row of a batch of several, and
-others at random.  The plain reference
-(``bench/reference/ppm.py``) folds each sampled sequence alone, padded to
-the bucket it was served in, and each served fold is held against it by four numbers, each the
-worst over the sample:
+others at random.  The plain reference of the configuration's model
+(``Reference`` of ``bench/models/<model>.py``) folds each sampled request
+alone, padded to the bucket it was served in, and each served fold is
+held against it by four numbers, each the worst over the sample:
 
 * ``tm_gap``: 1 - TM-score of the served C-alpha trace against the
   reference's, after optimal superposition (the paper's fidelity measure);
@@ -22,8 +22,6 @@ A configuration compares the numbers its file gives a limit for
 from __future__ import annotations
 
 import numpy as np
-
-from bench.reference.ppm import Reference
 
 NUMBERS = ("tm_gap", "coords_rel_rms", "disto_rel_rms", "disto_bin_mismatch")
 
@@ -94,15 +92,17 @@ def worst(per_fold: list[dict]) -> dict[str, float]:
 
 
 def compare(cfg: dict, params, outputs: list) -> tuple[dict, dict]:
-    """``outputs``: (aatype, bucket, coords, distogram) of each sampled
-    fold; the reference folds it padded to its bucket.  Returns (checks: {number: {"value", "limit"}} for the numbers the
+    """``outputs``: (request, bucket, coords, distogram) of each sampled
+    fold; the model's reference folds the request padded to its bucket.
+    Returns (checks: {number: {"value", "limit"}} for the numbers the
     configuration limits, readings: every number)."""
+    from bench.run import model_of
     if not outputs:
         return {}, {}
-    ref = Reference(cfg)
+    ref = model_of(cfg).Reference(cfg)
     per_fold = []
-    for aatype, bucket, coords, disto in outputs:
-        rc, rd = ref(params, aatype, bucket)
+    for request, bucket, coords, disto in outputs:
+        rc, rd = ref(params, request, bucket)
         per_fold.append(readings(coords, disto, rc, rd))
     got = worst(per_fold)
     checks = {k: {"value": got[k], "limit": v}

@@ -92,17 +92,21 @@ KERNELS = {"flash_mha_pallas": flash_call,
            "aaq_quantize_pallas": quantize_call}
 
 
-def roofline_share(run, kernel: str) -> float | None:
+def roofline_share(run, kernel: str, work=None) -> float | None:
     """``kernel``'s roofline share in a traced run, in %: the least time
     of its traced calls, each at the shapes it ran with, over the device
-    time they took.  None where the trace shows no call of it."""
+    time they took.  None where the trace shows no call of it.  ``work``
+    maps a call's instruction text to its ``Call``; by default the
+    kernel's entry in ``KERNELS``, so a later kernel's metric passes its
+    own from a file of its own."""
     if run.trace is None or not run.peak:
         return None
     spent = run.trace.kernel_s.get(kernel, 0.0)
     calls = run.trace.kernel_calls.get(kernel, {})
     if spent <= 0.0 or not calls:
         return None
-    least = sum(n * KERNELS[kernel](text).least_s(run.peak["bf16_flops_s"],
-                                                  run.peak["hbm_bytes_s"])
+    work = work or KERNELS[kernel]
+    least = sum(n * work(text).least_s(run.peak["bf16_flops_s"],
+                                       run.peak["hbm_bytes_s"])
                 for text, n in calls.items())
     return 100.0 * least / spent

@@ -7,20 +7,22 @@
 1. refuses to start unless JAX's first device is a TPU and the machine has
    the chips the cell asks for, and unless ``bench/peaks.json`` knows the
    chip (exit 1, no result);
-2. builds the cell's configuration (``bench/configs/<config>.json``) with
-   weights made on the device from ``--seed`` (``bench/weights.py``), and
-   the fold client that ``python -m repro.launch.serve --mode ppm`` serves
-   through, with the serving settings of the cell's traffic mix
-   (``bench/traffic/<traffic>.json``) and the scheme as the configuration
-   states it (its AAQ site table);
+2. builds the cell's configuration (``bench/configs/<config>.json``) and,
+   through the model module its ``"model"`` key names
+   (``bench/models/<model>.py``), weights made on the device from
+   ``--seed`` and the fold client that ``python -m repro.launch.serve
+   --mode ppm`` serves through, with the serving settings of the cell's
+   traffic mix (``bench/traffic/<traffic>.json``) and the scheme as the
+   configuration states it (its AAQ site table);
 3. compiles, or loads from the compile cache, every (bucket, launch size)
    the window runs: all of that is ``setup_s``;
-4. serves the mix's closed backlog (``bench/traffic/<kind>.py``) for
-   ``--seconds``; ``folds_per_s`` counts the folds of the batches retired
-   in the window over the time to the last of them;
+4. serves the mix's closed backlog (``bench/traffic/<kind>.py``, whose
+   requests the harness hands on untouched) for ``--seconds``;
+   ``folds_per_s`` counts the folds of the batches retired in the window
+   over the time to the last of them;
 5. reads the chip's peak memory, frees the server, and compares a sample
-   of the folds the window served with the plain reference
-   (``bench/reference/ppm.py``, ``bench/correct.py``);
+   of the folds the window served with the model module's plain
+   ``Reference`` (``bench/correct.py``);
 6. with ``--trace 1``, traces the window with the JAX profiler and reports
    the cell's per-layer metrics instead, each read by its own module
    ``bench/metrics/<metric>.py`` from the run's record and the trace
@@ -33,7 +35,11 @@ The same numbers end standard error.
 
 The harness is driven by data.  A later change adds a configuration, a
 traffic mix or a per-layer metric by adding its files and its entries in
-``BENCHMARK.json``; it edits no file that is already here.
+``BENCHMARK.json``; it edits no file that is already here.  A
+configuration of another fold model adds its model module
+(``bench/models/__init__.py`` lists what one defines); a new kernel's
+roofline passes its own work function to ``bench.ops.kernels.
+roofline_share`` from a new file under ``bench/ops/``.
 """
 from __future__ import annotations
 
@@ -56,6 +62,9 @@ BENCH = ROOT / "bench"
 # shadow the standard library's (``trace``): the package root goes there
 sys.path[:] = [str(ROOT), str(ROOT / "src")] + [
     p for p in sys.path if Path(p or ".").resolve() != BENCH]
+if __name__ == "__main__":
+    # the modules loaded by name import this one as bench.run: one copy
+    sys.modules.setdefault("bench.run", sys.modules[__name__])
 TRACE_DIR = ROOT / ".bench_trace"            # listed in .gitignore
 QUEUE_BATCHES = 2       # full batches kept queued in every bucket
 
@@ -130,6 +139,21 @@ def traffic_generator(traffic: dict):
     return importlib.import_module(f"bench.traffic.{traffic['kind']}")
 
 
+def model_of(cfg: dict):
+    """The model module ``bench/models/<model>.py`` the configuration's
+    ``"model"`` key names."""
+    model = cfg.get("model")
+    name = f"bench.models.{model}"
+    if not str(model).isidentifier():
+        raise Refused(f"no model {model!r}: not a module name")
+    try:
+        return importlib.import_module(name)
+    except ModuleNotFoundError as e:
+        if e.name != name:
+            raise
+        raise Refused(f"no model {model!r} in bench/models/") from None
+
+
 def metric_reader(name: str):
     return importlib.import_module(f"bench.metrics.{name}").read
 
@@ -186,12 +210,6 @@ def peak_bytes(device) -> int:
 # --------------------------------------------------------------------------
 # the server
 # --------------------------------------------------------------------------
-def program_config(cfg: dict):
-    from repro.models.ppm import PPMConfig
-    keys = PPMConfig.__dataclass_fields__
-    return PPMConfig(**{k: cfg[k] for k in keys})
-
-
 def program_scheme(cfg: dict):
     """The program's scheme as the configuration states it: under AAQ the
     configuration's site table (a site named by its last part), ahead of
@@ -230,14 +248,6 @@ def serve_args(cfg: dict, traffic: dict):
     return serve.build_parser().parse_args(argv)
 
 
-def make_client(cfg: dict, traffic: dict, params):
-    from repro.launch.serve import fold_client
-    args = serve_args(cfg, traffic)
-    args.scheme = program_scheme(cfg)
-    return fold_client(args, program_config(cfg), params,
-                       tuple(traffic["buckets"]))
-
-
 def warm_up(client, traffic: dict) -> None:
     """Compile, or load from the cache, every (bucket, cap) executable.
     No batch is served: on the chip a window's first batch of a bucket
@@ -249,16 +259,16 @@ def warm_up(client, traffic: dict) -> None:
 
 def serve_window(client, backlog, seconds: float):
     """Serve the backlog for ``seconds``; returns (window batches, every
-    batch served, seconds to the last retire in the window, the residues
-    of every request by id)."""
+    batch served, seconds to the last retire in the window, every request
+    by id, as the generator made it)."""
     pending: dict[int, list] = {b: [] for b in backlog.pool}
-    aatypes: dict[int, object] = {}
+    requests: dict[int, object] = {}
 
     def top_up():
         while min(len(v) for v in pending.values()) < QUEUE_BATCHES:
-            bucket, seqs = backlog.next_batch()
-            hs = [client.submit(s) for s in seqs]
-            aatypes.update((h.request_id, s) for h, s in zip(hs, seqs))
+            bucket, batch = backlog.next_batch()
+            hs = [client.submit(q) for q in batch]
+            requests.update((h.request_id, q) for h, q in zip(hs, batch))
             pending[bucket].append(hs)
 
     top_up()
@@ -295,7 +305,7 @@ def serve_window(client, backlog, seconds: float):
                 h.cancel()
     while client.core.inflight_count:
         retire_one()
-    return window, served, t_last, aatypes
+    return window, served, t_last, requests
 
 
 # --------------------------------------------------------------------------
@@ -308,10 +318,10 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float,
     import jax
 
     from bench import correct
-    from bench.weights import make_params
 
     w = cell(spec, workload)
     cfg = load_json(config_file(spec, w["config"]))
+    model = model_of(cfg)
     traffic = load_json(BENCH / "traffic" / f"{w['traffic']}.json")
     gen = traffic_generator(traffic)
     readers = {m["name"]: metric_reader(m["name"])
@@ -319,10 +329,10 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float,
     use_compile_cache()
     devices = devices or jax.devices()
 
-    params = make_params(cfg, seed)
+    params = model.make_params(cfg, seed)
     jax.block_until_ready(params)
     log(f"weights at {time.perf_counter() - T_PROCESS:.2f} s")
-    client = make_client(cfg, traffic, params)
+    client = model.make_client(cfg, traffic, params)
     caps = {b: client.core.batch_for_bucket(b) for b in traffic["buckets"]}
     warm_up(client, traffic)
     log(f"warm at {time.perf_counter() - T_PROCESS:.2f} s: caps {caps}, "
@@ -338,7 +348,7 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float,
     setup_s = time.perf_counter() - T_PROCESS
     try:
         with jax.profiler.TraceAnnotation("bench.window"):
-            window, served, window_s, aatypes = serve_window(
+            window, served, window_s, requests = serve_window(
                 client, backlog, seconds)
     finally:
         if trace:
@@ -351,20 +361,20 @@ def run_cell(spec: dict, workload: str, seed: int, seconds: float,
     spans = list(client.tracer.spans)
     sample = correct.sample([[r for r in b.results if r.ok] for b in served],
                             seed, traffic["sample"])
-    outputs = [(aatypes[r.request_id], r.bucket, r.coords,
+    outputs = [(requests[r.request_id], r.bucket, r.coords,
                 correct.distogram(r)) for r in sample]
     # keep only counts: the server, its outputs on the device and its
     # executables go before the reference runs
     run = Run(cfg=cfg, peak=peak,
               window=[b.slim() for b in window], window_s=window_s,
               compiles=compiles, spans=spans)
-    del client, window, served, sample, backlog, aatypes
+    del client, window, served, sample, backlog, requests
     gc.collect()
 
     t_ref = time.perf_counter()
     checks, got = correct.compare(cfg, params, outputs)
     log(f"reference {time.perf_counter() - t_ref:.2f} s over lengths "
-        f"{[len(o[0]) for o in outputs]}")
+        f"{[gen.length(o[0]) for o in outputs]}")
     failed = sum(not r.ok for b in run.window for r in b.results)
     ok = failed == 0 and bool(outputs) and all(
         c["value"] <= c["limit"] for c in checks.values())

@@ -55,7 +55,8 @@ def test_reference_is_the_server_in_float32(bucket, chunk):
     seq = np.random.default_rng(0).integers(0, 20, 37).astype(np.int32)
     aat, mask = pad_to_bucket([seq], bucket)
     out = jax.jit(lambda p, a, m: ppm_forward(
-        p, a, run.program_config(cfg), make_scheme("baseline_fp16"), mask=m,
+        p, a, run.model_of(cfg).program_config(cfg),
+        make_scheme("baseline_fp16"), mask=m,
         chunk_size=chunk))(params, jnp.asarray(aat), jnp.asarray(mask))
     out = {k: v[:, :37, :37] if k == "distogram" else v[:, :37]
            for k, v in out.items() if k in ("coords", "distogram")}

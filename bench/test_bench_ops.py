@@ -128,6 +128,12 @@ def test_roofline_share_reads_least_over_spent():
     run = SimpleNamespace(trace=trace, peak=peak)
     assert kernels.roofline_share(run, "flash_mha_pallas") == \
         pytest.approx(25.0)
+    # a kernel that ``KERNELS`` does not list, with its own work function
+    trace.kernel_s["new_kernel"] = 4 * least
+    trace.kernel_calls["new_kernel"] = {"text": 1}
+    assert kernels.roofline_share(
+        run, "new_kernel", work=lambda text: kernels.flash_call(FLASH)) == \
+        pytest.approx(25.0)
     # no call of the kernel in the trace, or no trace: nothing to read
     assert kernels.roofline_share(run, "aaq_quantize_pallas") is None
     assert kernels.roofline_share(SimpleNamespace(trace=None, peak=peak),

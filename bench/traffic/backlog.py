@@ -60,6 +60,11 @@ def bucket_order(weights: dict[int, float]):
         yield pick
 
 
+def length(request: np.ndarray) -> int:
+    """Residues of one request (an aatype array)."""
+    return len(request)
+
+
 class Backlog:
     """``next_batch()`` -> (bucket, [aatype arrays]) for one whole batch.
 
